@@ -151,7 +151,7 @@ def main(argv=None):
                             "attn": "BENCH_attention.json"}.get(
                                 args.family, "BENCH_backend.json")
 
-    results = {"family": args.family, "meta": env_metadata(interpret=True)}
+    results = {"family": args.family, "meta": env_metadata()}
     states = {}
     for bk in ("simulated", "fused"):
         if args.family == "cnn":

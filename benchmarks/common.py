@@ -28,15 +28,18 @@ def mean_std(vals):
     return m, s
 
 
-def env_metadata(interpret: bool = True) -> dict:
+def env_metadata() -> dict:
     """Machine/env metadata block for committed ``BENCH_*.json`` records.
 
     Stamped into every benchmark JSON so future comparisons (the
     ``benchmarks/check_regression.py`` gate) can tell apples from
     oranges: a CPU-interpret record must never be compared 1:1 against a
     real-TPU record, and a jax upgrade explains a step-time shift.
+    ``interpret_mode`` is the mode the kernels ran in, from the platform.
     """
     import jax
+
+    from repro.kernels import platform as kernel_platform
     return {
         "schema_version": 1,
         "jax": jax.__version__,
@@ -45,7 +48,7 @@ def env_metadata(interpret: bool = True) -> dict:
                               jax.devices()[0].platform)),
         "python": platform.python_version(),
         "machine": platform.machine(),
-        "interpret_mode": bool(interpret),
+        "interpret_mode": kernel_platform.interpret_mode(),
     }
 
 

@@ -193,7 +193,7 @@ def main(argv=None):
     report(rows, HEADER)
     if args.out:
         import json
-        payload = {"meta": env_metadata(interpret=True), "smoke": args.smoke,
+        payload = {"meta": env_metadata(), "smoke": args.smoke,
                    "rows": [dict(zip(HEADER, [str(v) for v in r]))
                             for r in rows]}
         with open(args.out, "w") as f:

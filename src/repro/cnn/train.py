@@ -149,6 +149,7 @@ def main(argv=None):
     import argparse
 
     from repro import telemetry
+    from repro.launch import compile_cache
     from repro.core.estimators import ALL_ESTIMATORS
 
     ap = argparse.ArgumentParser()
@@ -169,9 +170,9 @@ def main(argv=None):
                     help="execution backend for the quantization sites "
                          "(incl. the int8 conv contraction): 'simulated' = "
                          "jnp fake-quant + int32 XLA conv, 'fused' = the "
-                         "Pallas single-pass kernels via im2col (interpret "
-                         "mode on CPU; requires a fully-static --policy, "
-                         "i.e. hindsight or fixed)")
+                         "Pallas single-pass kernels via im2col (compiled "
+                         "on a TPU, interpreted elsewhere; requires a "
+                         "fully-static --policy, i.e. hindsight or fixed)")
     ap.add_argument("--telemetry", action="store_true",
                     help="per-site quantization health telemetry")
     ap.add_argument("--telemetry-out", default="",
@@ -183,6 +184,7 @@ def main(argv=None):
                     help="export a Chrome-trace JSON of the step phases "
                          "to PATH (view at https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.guard:
         args.telemetry = True
 

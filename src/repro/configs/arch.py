@@ -62,6 +62,10 @@ class ArchConfig:
     d_ff: int
     vocab: int
     source: str = ""
+    # A configuration cut to fit one chip names each key it changed from
+    # its source as (key, published, here) and the deployment it stands for.
+    reduced: tuple = ()
+    deployment: str = ""
 
     mlp_kind: str = "gelu"         # gelu|relu|sq_relu|swiglu|geglu|reglu
     norm_kind: str = "rmsnorm"

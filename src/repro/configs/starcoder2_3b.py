@@ -2,6 +2,10 @@
 
 30 layers, d_model 3072, 24 q heads / 2 kv heads, head_dim 128, d_ff 12288,
 vocab 49152, RoPE, biases, LayerNorm, gelu, sliding window 4096.
+
+``starcoder2-3b-4l`` is the same model cut to 4 layers, the depth one TPU
+v5e chip (16 GB) trains at seq 4096 with AdamW state; every width is the
+published one.
 """
 import dataclasses
 
@@ -36,3 +40,17 @@ def reduced() -> ArchConfig:
 
 
 register(CONFIG, reduced)
+
+# One chip's share of a training deployment at the published widths: the
+# 30 layers are split into pipeline stages of at most 4 layers, and this
+# chip holds one 4-layer stage.  The other 26 layers are the further stages
+# on further chips.  The embedding and the LM head, which belong to the
+# first and last stages, stay here too so that the loss is the model's.
+CHIP_CONFIG = register(dataclasses.replace(
+    CONFIG,
+    name="starcoder2-3b-4l",
+    n_layers=4,
+    reduced=(("n_layers", 30, 4),),
+    deployment=("pipeline-parallel training of starcoder2-3b, 4 of its 30 "
+                "layers per chip; the other 26 layers are further stages"),
+), reduced)

@@ -14,8 +14,8 @@ HBM traffic per element:  static  = read fp + write int8        (~5 B)
                                     + write int8                (~13 B)
 
 Grid: 2-D over (M, N) tiles.  Each grid cell writes its own (min, max)
-partial to a ``[gm, gn, 2]`` buffer; the tiny final reduction happens in
-the jit wrapper (``ops.fused_quantize``).  Per-tile partials keep every
+partial as one ``STATS_TILE`` of a ``[gm, gn, 8, 128]`` buffer; the tiny
+final reduction happens in the jit wrapper (``ops.fused_quantize``).  Per-tile partials keep every
 grid dimension ``parallel`` (no cross-iteration carries), which is both
 TPU-core-safe and megacore-friendly.
 
@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.quant import QuantSpec
+
+from . import STATS_TILE, platform, stats_tile
 
 DEFAULT_BLOCK = (256, 256)
 
@@ -57,8 +59,8 @@ def _kernel(x_ref, qparams_ref, q_ref, stats_ref, *, spec: QuantSpec,
     cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) + j * bn
     valid = jnp.logical_and(rows < m, cols < n)
     big = jnp.float32(jnp.finfo(jnp.float32).max)
-    stats_ref[0, 0, 0] = jnp.min(jnp.where(valid, x, big))
-    stats_ref[0, 0, 1] = jnp.max(jnp.where(valid, x, -big))
+    stats_ref[0, 0] = stats_tile(jnp.min(jnp.where(valid, x, big)),
+                                 jnp.max(jnp.where(valid, x, -big)))
 
 
 def fused_quantize_kernel(
@@ -67,14 +69,13 @@ def fused_quantize_kernel(
     *,
     spec: QuantSpec,
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
 ):
     """Raw pallas_call over a 2-D view (shape plumbing in ``ops``).
 
     Returns ``(q, partials)`` with ``q`` int8 (symmetric grid directly, or
     the asymmetric [0, 255] grid stored shifted by -128 so storage stays
-    int8/MXU-native) and ``partials`` fp32 ``[gm, gn, 2]`` per-tile
-    (min, max).
+    int8/MXU-native) and ``partials`` fp32 ``[gm, gn, 8, 128]`` per-tile
+    (min, max) in rows 0 and 1.
     """
     m, n = x.shape
     bm, bn = min(block[0], m), min(block[1], n)
@@ -93,11 +94,11 @@ def fused_quantize_kernel(
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1, 2), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, 1) + STATS_TILE, lambda i, j: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.int8),
-            jax.ShapeDtypeStruct((gm, gn, 2), jnp.float32),
+            jax.ShapeDtypeStruct((gm, gn) + STATS_TILE, jnp.float32),
         ],
-        interpret=interpret,
+        interpret=platform.interpret_mode(),
     )(x, qparams)

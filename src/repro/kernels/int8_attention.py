@@ -53,8 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import QuantSpec
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from . import STATS_TILE, platform, stats_tile, stats_values
 
 NEG_INF = -1e30           # matches models/attention.py: finite, NaN-free
 P_SPEC = QuantSpec(bits=8, symmetric=False)   # probability grid [0, 255]
@@ -249,8 +248,12 @@ def _accumulate(acc_prev, l_prev, corr, acc_pv, rp, alpha_pv, scale_p):
     return acc, l
 
 
-def _stats_update(st, p, p_hat, sv, p_lo, p_hi):
-    """Fold one tile into the (pmin, pmax, clip, n, err, sig) partials.
+# How each (pmin, pmax, clip, n, err, sig) slot folds in one tile's value.
+_STAT_FOLD = (jnp.minimum, jnp.maximum, jnp.add, jnp.add, jnp.add, jnp.add)
+
+
+def _tile_stats(p, p_hat, sv, p_lo, p_hi):
+    """One tile's (pmin, pmax, clip, n, err, sig) contributions.
 
     ``sv`` masks to in-bounds entries (rows < sq, cols < skv); min/max and
     the integer-valued counters are exact in any association, err/sig use
@@ -264,18 +267,26 @@ def _stats_update(st, p, p_hat, sv, p_lo, p_hi):
     cnt = jnp.sum(jnp.where(sv, 1.0, 0.0), axis=(-2, -1))
     err = _tree_sum_last2(_fence(jnp.where(sv, (p - p_hat) ** 2, 0.0)))
     sig = _tree_sum_last2(_fence(jnp.where(sv, p * p, 0.0)))
-    return jnp.stack([jnp.minimum(st[..., 0], pmn),
-                      jnp.maximum(st[..., 1], pmx),
-                      st[..., 2] + clip,
-                      st[..., 3] + cnt,
-                      st[..., 4] + err,
-                      st[..., 5] + sig], axis=-1)
+    return pmn, pmx, clip, cnt, err, sig
+
+
+def _stats_update(st, p, p_hat, sv, p_lo, p_hi):
+    """Fold one tile into ``[..., STAT_SLOTS]`` partials (the reference's
+    layout; the kernel folds the same values into its stats tile)."""
+    inc = _tile_stats(p, p_hat, sv, p_lo, p_hi)
+    return jnp.stack([fold(st[..., r], v) for r, (fold, v)
+                      in enumerate(zip(_STAT_FOLD, inc))], axis=-1)
+
+
+def _stats_init_values():
+    big = jnp.float32(jnp.finfo(jnp.float32).max)
+    z = jnp.float32(0.0)
+    return big, -big, z, z, z, z
 
 
 def _stats_init(shape=()):
-    big = jnp.float32(jnp.finfo(jnp.float32).max)
     z = jnp.zeros(shape, jnp.float32)
-    return jnp.stack([z + big, z - big, z, z, z, z], axis=-1)
+    return jnp.stack([z + v for v in _stats_init_values()], axis=-1)
 
 
 def reduce_pstats(partials: jax.Array):
@@ -297,9 +308,19 @@ def reduce_pstats(partials: jax.Array):
 # Grid: (BH, nq, width) — heads and q blocks parallel, the kv walk is the
 # sequential ("arbitrary") dimension carrying the online-softmax scratch.
 # ---------------------------------------------------------------------------
-def _attn_kernel(q_ref, k_ref, v_ref, regs_ref, kvlen_ref,
+def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
                  out_ref, ml_ref, ps_ref,
                  m_sc, l_sc, acc_sc, st_sc, *, sched: AttnSchedule):
+    """``q_ref`` holds q shifted onto the signed grid (``q - 128``, int8) and
+    ``ksum_ref`` the per-key sums of k over the head dim, so that both
+    contractions run int8 x int8 on the MXU:
+
+        sum_h (q - zp_q) k   = sum_h (q - 128) k + (128 - zp_q) ksum
+        sum_j (p - zp_p) v   = sum_j (p - 128) v + (128 - zp_p) sum_j v
+
+    Both identities are exact in int32, so the accumulators equal the
+    reference's int32 einsums of the zero-point-corrected images.
+    ``st_sc`` is a ``STATS_TILE`` whose row ``r`` holds stat slot ``r``."""
     S = sched
     i = pl.program_id(1)
     t = pl.program_id(2)
@@ -309,7 +330,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, regs_ref, kvlen_ref,
         m_sc[...] = jnp.full((S.bq, 1), NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros((S.bq, 1), jnp.float32)
         acc_sc[...] = jnp.zeros((S.bq, S.hd), jnp.float32)
-        st_sc[0] = _stats_init()
+        st_sc[...] = stats_tile(*_stats_init_values())
 
     ki = _kv_block_base(i, S) + t
 
@@ -323,12 +344,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, regs_ref, kvlen_ref,
         p_hi = regs_ref[0, 6]
         kvlen = kvlen_ref[0, 0]
 
-        rq = q_ref[0].astype(jnp.int32) - zp_q.astype(jnp.int32)   # [bq, hd]
-        rk = k_ref[0].astype(jnp.int32)                            # [bkv, hd]
-        rv = v_ref[0].astype(jnp.int32)
+        k = k_ref[0]                                               # [bkv, hd]
+        v = v_ref[0]
         acc_qk = jax.lax.dot_general(
-            rq, rk, (((1,), (1,)), ((), ())),
+            q_ref[0], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.int32)                      # [bq, bkv]
+        acc_qk = acc_qk + (128 - zp_q.astype(jnp.int32)) * ksum_ref[0]
 
         q_pos = i * S.bq + jax.lax.broadcasted_iota(
             jnp.int32, (S.bq, S.bkv), 0)
@@ -338,9 +359,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, regs_ref, kvlen_ref,
 
         rp, p, p_hat, m_new, corr = _scores_to_probs(
             acc_qk, mask, m_sc[...], alpha_qk, scale_p, zp_p)
+        zp_p_i = zp_p.astype(jnp.int32)
+        p_s8 = (rp + (zp_p_i - 128)).astype(jnp.int8)
+        vsum = jnp.sum(v.astype(jnp.int32), axis=0, keepdims=True)  # [1, hd]
         acc_pv = jax.lax.dot_general(
-            rp, rv, (((1,), (0,)), ((), ())),
+            p_s8, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)                      # [bq, hd]
+        acc_pv = acc_pv + (128 - zp_p_i) * vsum
         acc, l = _accumulate(acc_sc[...], l_sc[...], corr, acc_pv, rp,
                              alpha_pv, scale_p)
         acc_sc[...] = acc
@@ -348,7 +373,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, regs_ref, kvlen_ref,
         m_sc[...] = m_new
 
         sv = (q_pos < S.sq) & (k_pos < S.skv)
-        st_sc[0] = _stats_update(st_sc[0], p, p_hat, sv, p_lo, p_hi)
+        inc = _tile_stats(p, p_hat, sv, p_lo, p_hi)
+        st = st_sc[...]
+        rows = jax.lax.broadcasted_iota(jnp.int32, STATS_TILE, 0)
+        new = st
+        for r, (fold, val) in enumerate(zip(_STAT_FOLD, inc)):
+            new = jnp.where(rows == r, fold(st, val), new)
+        st_sc[...] = new
 
     vis = _block_visited(i, ki, S)
     if vis is None:
@@ -361,11 +392,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, regs_ref, kvlen_ref,
         l = l_sc[...]
         out_ref[0] = acc_sc[...] / jnp.maximum(l, 1e-30)
         ml_ref[0] = jnp.concatenate([m_sc[...], l], axis=1)
-        ps_ref[0, 0] = st_sc[0]
+        ps_ref[0, 0] = st_sc[...]
 
 
-def attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, *,
-                     sched: AttnSchedule, interpret: bool = True):
+def attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, *, sched: AttnSchedule):
     """Raw pallas_call.  ``q_u8`` uint8 [BH, sq, hd]; ``k_i8``/``v_i8``
     int8 [ZB, skv, hd] (ZB = BH // groups); ``regs`` fp32 [1, 8]; ``kvlen``
     int32 [1, 1].  Returns ``(out [BH, sq, hd] f32, ml [BH, sq, 2] f32,
@@ -373,41 +403,48 @@ def attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, *,
     S = sched
     bh = q_u8.shape[0]
     g = S.groups
+    q_s8 = (q_u8.astype(jnp.int16) - 128).astype(jnp.int8)
+    ksum = jnp.sum(k_i8.astype(jnp.int32), axis=-1)[:, None, :]   # [ZB,1,skv]
 
     def kvmap(b, i, t):
         return (b // g, _kv_block_base(i, S) + t, 0)
 
-    return pl.pallas_call(
+    def ksum_map(b, i, t):
+        return (b // g, 0, _kv_block_base(i, S) + t)
+
+    out, ml, ps = pl.pallas_call(
         functools.partial(_attn_kernel, sched=S),
         grid=(bh, S.nq, S.width),
         in_specs=[
             pl.BlockSpec((1, S.bq, S.hd), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, S.bkv, S.hd), kvmap),
             pl.BlockSpec((1, S.bkv, S.hd), kvmap),
+            pl.BlockSpec((1, 1, S.bkv), ksum_map),
             pl.BlockSpec((1, 8), lambda b, i, t: (0, 0)),
             pl.BlockSpec((1, 1), lambda b, i, t: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, S.bq, S.hd), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, S.bq, 2), lambda b, i, t: (b, i, 0)),
-            pl.BlockSpec((1, 1, STAT_SLOTS), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, 1) + STATS_TILE, lambda b, i, t: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, S.sq, S.hd), jnp.float32),
             jax.ShapeDtypeStruct((bh, S.sq, 2), jnp.float32),
-            jax.ShapeDtypeStruct((bh, S.nq, STAT_SLOTS), jnp.float32),
+            jax.ShapeDtypeStruct((bh, S.nq) + STATS_TILE, jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((S.bq, 1), jnp.float32),
             pltpu.VMEM((S.bq, 1), jnp.float32),
             pltpu.VMEM((S.bq, S.hd), jnp.float32),
-            pltpu.VMEM((1, STAT_SLOTS), jnp.float32),
+            pltpu.VMEM(STATS_TILE, jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
-    )(q_u8, k_i8, v_i8, regs, kvlen)
+        interpret=platform.interpret_mode(),
+    )(q_s8, k_i8, v_i8, ksum, regs, kvlen)
+    return out, ml, stats_values(ps, STAT_SLOTS)
 
 
 # ---------------------------------------------------------------------------

@@ -28,8 +28,8 @@ Layout conventions (see ``ops.py``):
     bit-exact even across backends with different fusion choices.
 
 Grid: (gm, gn, gk), K innermost ("arbitrary" — sequential accumulation
-into a VMEM scratch tile); (i, j) are parallel.  Stats partials are
-per-(i, j) so no cross-core races.
+into a VMEM scratch tile); (i, j) are parallel.  Stats partials are one
+``STATS_TILE`` per (i, j), so no cross-core races.
 """
 from __future__ import annotations
 
@@ -42,9 +42,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import QuantSpec
 
-# jax renamed TPUCompilerParams -> CompilerParams across releases; accept both.
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from . import STATS_TILE, platform, stats_tile
 
 DEFAULT_BLOCK = (256, 256, 256)  # (bm, bn, bk)
 
@@ -61,7 +59,7 @@ def _kernel(x_ref, w_ref, alpha_ref, corr_ref, outqp_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)
+    x = x_ref[...]
     if kdim % bk != 0:
         # K-edge block: out-of-bounds reads are unspecified (interpret mode
         # pads with a sentinel, hardware with whatever is resident), so the
@@ -69,11 +67,13 @@ def _kernel(x_ref, w_ref, alpha_ref, corr_ref, outqp_ref,
         # (M) / cols (N) raggedness needs no masking here: those lanes land
         # outside the output write window and outside the stats mask.
         kcol = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1) + k * bk
-        x = jnp.where(kcol < kdim, x, 0)
+        x = jnp.where(kcol < kdim, x, jnp.int8(0))
 
+    # int8 x int8 -> int32 on the MXU (the chip's matmul takes no int32
+    # operands); the accumulation is exact.
     acc_ref[...] += jax.lax.dot_general(
         x,
-        w_ref[...].astype(jnp.int32),
+        w_ref[...],
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
@@ -88,8 +88,8 @@ def _kernel(x_ref, w_ref, alpha_ref, corr_ref, outqp_ref,
         cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) + j * bn
         valid = jnp.logical_and(rows < m, cols < n)
         big = jnp.float32(jnp.finfo(jnp.float32).max)
-        stats_ref[0, 0, 0] = jnp.min(jnp.where(valid, y, big))
-        stats_ref[0, 0, 1] = jnp.max(jnp.where(valid, y, -big))
+        stats_ref[0, 0] = stats_tile(jnp.min(jnp.where(valid, y, big)),
+                                     jnp.max(jnp.where(valid, y, -big)))
 
         scale = outqp_ref[0, 0]  # pre-computed (scale, zp) requant registers
         zp = outqp_ref[0, 1]
@@ -109,15 +109,15 @@ def _fp_kernel(x_ref, w_ref, alpha_ref, corr_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[0].astype(jnp.int32)
+    x = x_ref[0]
     if kdim % bk != 0:
         # Ragged contraction tail: see the requant kernel above.
         kcol = jax.lax.broadcasted_iota(jnp.int32, (bm, bk), 1) + k * bk
-        x = jnp.where(kcol < kdim, x, 0)
+        x = jnp.where(kcol < kdim, x, jnp.int8(0))
 
     acc_ref[...] += jax.lax.dot_general(
         x,
-        w_ref[0].astype(jnp.int32),
+        w_ref[0],
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.int32,
     )
@@ -131,8 +131,8 @@ def _fp_kernel(x_ref, w_ref, alpha_ref, corr_ref,
         cols = jax.lax.broadcasted_iota(jnp.int32, (bm, bn), 1) + j * bn
         valid = jnp.logical_and(rows < m, cols < n)
         big = jnp.float32(jnp.finfo(jnp.float32).max)
-        stats_ref[0, 0, 0, 0] = jnp.min(jnp.where(valid, y, big))
-        stats_ref[0, 0, 0, 1] = jnp.max(jnp.where(valid, y, -big))
+        stats_ref[0, 0, 0] = stats_tile(jnp.min(jnp.where(valid, y, big)),
+                                        jnp.max(jnp.where(valid, y, -big)))
         y_ref[...] = y[None]
 
 
@@ -143,7 +143,6 @@ def int8_matmul_fp_kernel(
     corr: jax.Array,      # int32 [B, 1, N] = (128 - zp_x) * colsum(w)
     *,
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
 ):
     """Variant of :func:`int8_matmul_fused_kernel` for matmul sites whose
     output feeds a *non-linear* consumer (norm, activation, attention core)
@@ -156,7 +155,7 @@ def int8_matmul_fp_kernel(
 
     The extra leading dimension ``B`` batches per-slice weights (MoE
     experts); pass ``B == 1`` for plain 2-D matmuls.  Returns
-    ``(y fp32 [B, M, N], partials fp32 [B, gm, gn, 2])``.
+    ``(y fp32 [B, M, N], partials fp32 [B, gm, gn, 8, 128])``.
     """
     b, m, k = x_q.shape
     b2, k2, n = w_q.shape
@@ -178,18 +177,19 @@ def int8_matmul_fp_kernel(
         ],
         out_specs=[
             pl.BlockSpec((1, bm, bn), lambda b, i, j, k: (b, i, j)),
-            pl.BlockSpec((1, 1, 1, 2), lambda b, i, j, k: (b, i, j, 0)),
+            pl.BlockSpec((1, 1, 1) + STATS_TILE,
+                         lambda b, i, j, k: (b, i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, m, n), jnp.float32),
-            jax.ShapeDtypeStruct((b, gm, gn, 2), jnp.float32),
+            jax.ShapeDtypeStruct((b, gm, gn) + STATS_TILE, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=platform.interpret_mode(),
     )(x_q, w_q, alpha, corr)
 
 
@@ -202,7 +202,6 @@ def int8_matmul_fused_kernel(
     *,
     out_spec: QuantSpec,
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
 ):
     m, k = x_q.shape
     k2, n = w_q.shape
@@ -227,15 +226,15 @@ def int8_matmul_fused_kernel(
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-            pl.BlockSpec((1, 1, 2), lambda i, j, k: (i, j, 0)),
+            pl.BlockSpec((1, 1) + STATS_TILE, lambda i, j, k: (i, j, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), jnp.int8),
-            jax.ShapeDtypeStruct((gm, gn, 2), jnp.float32),
+            jax.ShapeDtypeStruct((gm, gn) + STATS_TILE, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=platform.interpret_mode(),
     )(x_q, w_q, alpha, corr, out_qparams)

@@ -2,10 +2,10 @@
 
 Handles shape plumbing (arbitrary rank -> 2-D tiles -> back), the
 int8-storage convention (asymmetric [0, 255] grids are stored shifted by
--128 so all storage/compute stays int8), partial-statistics reduction, and
-interpret-mode switching (interpret=True executes the kernel body on CPU —
-that is how this CPU-only container validates the TPU kernels against the
-``ref.py`` oracles).
+-128 so all storage/compute stays int8) and the partial-statistics
+reduction.  Off the TPU the kernels run in Pallas interpret mode (see
+``repro.kernels.platform.interpret_mode``), which is how the CPU test
+suite checks them against the ``ref.py`` oracles.
 
 All wrappers return *core-convention* integers (uint8 asymmetric / int8
 symmetric) so results are directly comparable with
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.core.quant import QuantSpec, scale_zero_point
 
-from . import tuning
+from . import stats_values, tuning
 from .fused_quantize import DEFAULT_BLOCK, fused_quantize_kernel
 from .int8_attention import AttnSchedule, attention_kernel
 from .int8_matmul import int8_matmul_fp_kernel, int8_matmul_fused_kernel
@@ -55,10 +55,12 @@ def _unshift(q_i8: jax.Array, spec: QuantSpec) -> jax.Array:
 
 
 def _reduce_partials(partials: jax.Array) -> tuple[jax.Array, jax.Array]:
-    return jnp.min(partials[..., 0]), jnp.max(partials[..., 1])
+    """Site (min, max) from ``[..., 8, 128]`` per-tile statistics tiles."""
+    mn_mx = stats_values(partials, 2)
+    return jnp.min(mn_mx[..., 0]), jnp.max(mn_mx[..., 1])
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("spec", "block"))
 def fused_quantize(
     x: jax.Array,
     qmin: jax.Array,
@@ -66,7 +68,6 @@ def fused_quantize(
     *,
     spec: QuantSpec = QuantSpec(bits=8, symmetric=False),
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
 ):
     """Single-pass static quantize + stats.  Returns ``(q, obs_min, obs_max)``.
 
@@ -78,15 +79,12 @@ def fused_quantize(
     with jax.named_scope("k_fused_quantize"):
         x2, shape = _as_2d(x)
         q, partials = fused_quantize_kernel(
-            x2, _qparams(qmin, qmax, spec), spec=spec, block=block,
-            interpret=interpret
-        )
+            x2, _qparams(qmin, qmax, spec), spec=spec, block=block)
         mn, mx = _reduce_partials(partials)
         return _unshift(q, spec).reshape(shape), mn, mx
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "block", "interpret",
-                                             "on_chip_prng"))
+@functools.partial(jax.jit, static_argnames=("spec", "block", "on_chip_prng"))
 def stochastic_quantize(
     x: jax.Array,
     qmin: jax.Array,
@@ -95,7 +93,6 @@ def stochastic_quantize(
     *,
     spec: QuantSpec = QuantSpec(bits=8, symmetric=False, stochastic=True),
     block=DEFAULT_BLOCK,
-    interpret: bool = True,
     on_chip_prng: bool = False,
     seed=None,
 ):
@@ -111,20 +108,19 @@ def stochastic_quantize(
         if on_chip_prng:
             q, partials = stochastic_quantize_kernel(
                 x2, _qparams(qmin, qmax, spec), None, spec=spec, block=block,
-                interpret=interpret, on_chip_prng=True, seed=seed,
+                on_chip_prng=True, seed=seed,
             )
         else:
             n2, _ = _as_2d(noise)
             q, partials = stochastic_quantize_kernel(
                 x2, _qparams(qmin, qmax, spec), n2, spec=spec, block=block,
-                interpret=interpret,
             )
         mn, mx = _reduce_partials(partials)
         return _unshift(q, spec).reshape(shape), mn, mx
 
 
 @functools.partial(
-    jax.jit, static_argnames=("out_spec", "block", "interpret", "has_bias")
+    jax.jit, static_argnames=("out_spec", "block", "has_bias")
 )
 def _int8_matmul_fused(
     x_q: jax.Array,
@@ -138,7 +134,6 @@ def _int8_matmul_fused(
     *,
     out_spec: QuantSpec,
     block,
-    interpret: bool,
     has_bias: bool,
 ):
     m, k = x_q.shape
@@ -159,7 +154,7 @@ def _int8_matmul_fused(
             ).astype(jnp.int32)
         q, partials = int8_matmul_fused_kernel(
             xs, w_q, alpha, corr, _qparams(out_qmin, out_qmax, out_spec),
-            out_spec=out_spec, block=block, interpret=interpret,
+            out_spec=out_spec, block=block,
         )
         mn, mx = _reduce_partials(partials)
         return _unshift(q, out_spec), mn, mx
@@ -239,7 +234,7 @@ def _prod(dims) -> int:
     return out
 
 
-def _int8_fp_batched(x3, w3, x_zp, alpha, block, interpret):
+def _int8_fp_batched(x3, w3, x_zp, alpha, block):
     """Shared int8 epilogue for the batched fp-out MXU kernel: shift the
     asymmetric uint8 activations onto the signed grid, fold the
     zero-point correction into the integer ``corr`` operand, run the
@@ -252,9 +247,8 @@ def _int8_fp_batched(x3, w3, x_zp, alpha, block, interpret):
     colsum = jnp.sum(w3.astype(jnp.int32), axis=1, keepdims=True)
     corr = jnp.round(128.0 - jnp.asarray(x_zp, jnp.float32)
                      ).astype(jnp.int32) * colsum
-    y3, partials = int8_matmul_fp_kernel(
-        xs, w3, alpha2, corr, block=tuple(block), interpret=interpret
-    )
+    y3, partials = int8_matmul_fp_kernel(xs, w3, alpha2, corr,
+                                         block=tuple(block))
     mn, mx = _reduce_partials(partials)
     return y3, mn, mx
 
@@ -278,7 +272,6 @@ def int8_matmul_fp(
     *,
     plan: EinsumPlan,
     block=None,
-    interpret: bool = True,
 ):
     """Quantized-site einsum on the int8 MXU path with an fp32 result.
 
@@ -298,10 +291,10 @@ def int8_matmul_fp(
         _, m, k, n = _einsum_dims(plan, x_q.shape, w_q.shape)
         block = tuning.matmul_block(m, n, k, dtype=str(x_q.dtype))
     return _int8_matmul_fp_jit(x_q, w_q, x_zp, alpha, plan=plan,
-                               block=tuple(block), interpret=interpret)
+                               block=tuple(block))
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("plan", "block"))
 def _int8_matmul_fp_jit(
     x_q: jax.Array,
     w_q: jax.Array,
@@ -310,7 +303,6 @@ def _int8_matmul_fp_jit(
     *,
     plan: EinsumPlan,
     block,
-    interpret: bool,
 ):
     with jax.named_scope("k_int8_matmul_fp"):
         nb, nxf, nc, nwf = (plan.n_batch, plan.n_x_free, plan.n_contract,
@@ -325,7 +317,7 @@ def _int8_matmul_fp_jit(
 
         y3, mn, mx = _int8_fp_batched(xt.reshape(b, m, k),
                                       wt.reshape(b, k, n),
-                                      x_zp, alpha, block, interpret)
+                                      x_zp, alpha, block)
         y = jnp.transpose(y3.reshape(bdims + mdims + ndims), plan.y_perm)
         return y, mn, mx
 
@@ -522,7 +514,6 @@ def int8_conv_fp(
     *,
     plan: ConvPlan,
     block=None,
-    interpret: bool = True,
 ):
     """Eager tile-resolving wrapper — see :func:`int8_matmul_fp` for why
     tuning happens outside jit.  The lowered conv is the [G, M, K] x
@@ -531,10 +522,10 @@ def int8_conv_fp(
         block = tuning.matmul_block(plan.m, plan.cout_g, plan.k,
                                     dtype=str(x_q.dtype))
     return _int8_conv_fp_jit(x_q, w_q, x_zp, alpha, plan=plan,
-                             block=tuple(block), interpret=interpret)
+                             block=tuple(block))
 
 
-@functools.partial(jax.jit, static_argnames=("plan", "block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("plan", "block"))
 def _int8_conv_fp_jit(
     x_q: jax.Array,
     w_q: jax.Array,
@@ -543,7 +534,6 @@ def _int8_conv_fp_jit(
     *,
     plan: ConvPlan,
     block,
-    interpret: bool,
 ):
     """Quantized conv on the int8 MXU path with an fp32 result.
 
@@ -560,12 +550,11 @@ def _int8_conv_fp_jit(
         pad_q = jnp.round(jnp.asarray(x_zp, jnp.float32)).astype(x_q.dtype)
         patches = conv_patches(x_q, plan, pad_q)     # fp 0.0 == integer zp
         ws = conv_lower_weights(w_q, plan)
-        y3, mn, mx = _int8_fp_batched(patches, ws, x_zp, alpha, block,
-                                      interpret)
+        y3, mn, mx = _int8_fp_batched(patches, ws, x_zp, alpha, block)
         return conv_unlower_output(y3, plan), mn, mx
 
 
-@functools.partial(jax.jit, static_argnames=("sched", "interpret"))
+@functools.partial(jax.jit, static_argnames=("sched",))
 def int8_attention_fp(
     q_u8: jax.Array,         # uint8 [BH, sq, hd], asymmetric grid
     k_i8: jax.Array,         # int8  [ZB, skv, hd], symmetric
@@ -574,19 +563,17 @@ def int8_attention_fp(
     kvlen: jax.Array,        # int32 [1, 1] runtime kv length bound
     *,
     sched: AttnSchedule,
-    interpret: bool = True,
 ):
     """Fused flash-style int8 attention core with in-kernel p-site stats.
 
     Returns ``(out fp32 [BH, sq, hd], ml fp32 [BH, sq, 2] final softmax
-    (max, denom) residuals, pstats fp32 [BH, nq, 6] per-(head, q block)
-    probability statistics partials)``.  The block plan is baked into
+    (max, denom) residuals, pstats fp32 [BH, nq, 8, 128] per-(head, q
+    block) probability statistics tiles)``.  The block plan is baked into
     ``sched`` at dispatch (resolved via :mod:`repro.kernels.tuning`), so
     both backends replay the identical schedule.
     """
     with jax.named_scope("k_attn_fwd"):
-        return attention_kernel(q_u8, k_i8, v_i8, regs, kvlen,
-                                sched=sched, interpret=interpret)
+        return attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, sched=sched)
 
 
 def int8_matmul_fused(
@@ -598,7 +585,6 @@ def int8_matmul_fused(
     *,
     out_spec: QuantSpec = QuantSpec(bits=8, symmetric=False),
     block=(256, 256, 256),
-    interpret: bool = True,
 ):
     """Full paper layer data path: int8 GEMM + fused dequant/stats/requant.
 
@@ -613,6 +599,5 @@ def int8_matmul_fused(
         jnp.asarray(x_scale, jnp.float32), jnp.asarray(x_zp, jnp.float32),
         jnp.asarray(w_scale, jnp.float32), bias,
         jnp.asarray(out_qmin, jnp.float32), jnp.asarray(out_qmax, jnp.float32),
-        out_spec=out_spec, block=tuple(block), interpret=interpret,
-        has_bias=has_bias,
+        out_spec=out_spec, block=tuple(block), has_bias=has_bias,
     )

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 This is the proof that the distribution config is coherent without real
@@ -16,24 +13,21 @@ Usage:
     python -m repro.launch.dryrun --all            # every runnable cell,
                                                    # one subprocess per cell
 Outputs one JSON per cell under --out (default experiments/dryrun/).
+
+It is a CPU stand-in: ``main`` pins this process and the children it
+starts to the CPU backend with 512 host devices, so it never takes a TPU
+that a child would then need.
 """
 import argparse
 import gc
 import json
+import os
 import re
 import subprocess
 import sys
 import time
 
 import jax
-
-# Stochastic-rounding noise must be generated SHARDED: partitionable
-# threefry lets GSPMD split the bit generation with the consuming tensor.
-# (The rbg RngBitGenerator alternative is NOT partitionable — measured as a
-# 26 GB/layer replicated-noise disaster, EXPERIMENTS.md §Perf iteration 3.)
-jax.config.update("jax_threefry_partitionable", True)
-
-import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
@@ -319,6 +313,16 @@ def main():
     ap.add_argument("--tag", default="")
     ap.add_argument("--timeout", type=int, default=3000)
     args = ap.parse_args()
+
+    # Before the first backend use; the environment reaches the children.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+    # Stochastic-rounding noise must be generated SHARDED: partitionable
+    # threefry lets GSPMD split the bit generation with the consuming
+    # tensor (the rbg RngBitGenerator alternative is not partitionable and
+    # replicates the noise).
+    jax.config.update("jax_threefry_partitionable", True)
 
     if args.all:
         meshes = [False, True] if args.both_meshes else [args.multipod]

@@ -16,13 +16,13 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
-import traceback
 
 import jax
 import jax.numpy as jnp
 
 from repro import checkpoint, configs, data, telemetry
 from repro.core.policy import QuantPolicy
+from repro.launch import compile_cache
 from repro.models import model
 
 
@@ -39,8 +39,6 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default="",
                     help="restore trained params + calibrated ranges")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--verbose", action="store_true",
-                    help="full tracebacks on restore failure")
     ap.add_argument("--telemetry", default="",
                     help="write per-site prefill quantization health "
                          "(clip/SQNR/util) as JSONL to this path")
@@ -49,6 +47,7 @@ def main(argv=None):
                          "phases (prefill / per-step decode / telemetry) "
                          "to PATH — view at https://ui.perfetto.dev")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     tracer = telemetry.Tracer(enabled=bool(args.trace))
 
     cfg = configs.get_reduced(args.arch) if args.reduced \
@@ -63,29 +62,28 @@ def main(argv=None):
     params = model.init_params(jax.random.PRNGKey(args.seed), cfg)
     quant = model.init_quant_state(cfg, policy)
     if args.ckpt_dir:
+        # A failed restore ends the run: serving random weights in place of
+        # the asked-for checkpoint would look like success.
         latest = checkpoint.latest_step(args.ckpt_dir)
+        if latest is None:
+            raise SystemExit(f"[serve] no checkpoint in {args.ckpt_dir}")
         try:
-            try:
-                st = checkpoint.restore(args.ckpt_dir, latest,
-                                        {"params": params, "quant": quant})
-            except ValueError:
-                if not policy.telemetry.enabled:
-                    raise
-                # Pre-telemetry checkpoint (width-3 quant leaves): restore
-                # the classic layout, then widen — ranges carry over.
-                st = checkpoint.restore(
-                    args.ckpt_dir, latest,
-                    {"params": params, "quant": model.init_quant_state(cfg)})
-                st["quant"] = telemetry.widen_state(st["quant"],
-                                                    policy.stat_width)
-                print("[serve] migrated width-3 quant state to telemetry "
-                      "layout")
-            params, quant = st["params"], st["quant"]
-            print(f"[serve] restored step {latest}")
-        except Exception as e:
-            if args.verbose:
-                traceback.print_exc()
-            print(f"[serve] restore failed ({e}); serving from init")
+            st = checkpoint.restore(args.ckpt_dir, latest,
+                                    {"params": params, "quant": quant})
+        except ValueError:
+            if not policy.telemetry.enabled:
+                raise
+            # Pre-telemetry checkpoint (width-3 quant leaves): restore
+            # the classic layout, then widen — ranges carry over.
+            st = checkpoint.restore(
+                args.ckpt_dir, latest,
+                {"params": params, "quant": model.init_quant_state(cfg)})
+            st["quant"] = telemetry.widen_state(st["quant"],
+                                                policy.stat_width)
+            print("[serve] migrated width-3 quant state to telemetry "
+                  "layout")
+        params, quant = st["params"], st["quant"]
+        print(f"[serve] restored step {latest}")
 
     stream = data.for_arch(cfg, seq_len=args.prompt_len + args.gen,
                            global_batch=args.batch, seed=args.seed)
@@ -99,8 +97,10 @@ def main(argv=None):
     want_stats = bool(args.telemetry) and policy.telemetry.enabled
     prefill = jax.jit(lambda p, q, b: model.prefill(
         p, q, b, cfg, policy, cache_len=cache_len, return_stats=want_stats))
+    # The caches are donated: each decode step writes one token into them,
+    # and without donation the old and the new caches are live at once.
     decode = jax.jit(lambda p, q, t, pos, c: model.decode_step(
-        p, q, t, pos, c, cfg, policy))
+        p, q, t, pos, c, cfg, policy), donate_argnums=(4,))
 
     t0 = time.perf_counter()
     # The first prefill/decode call compiles — the trace shows it as one
@@ -115,6 +115,7 @@ def main(argv=None):
             prefill_stats = None
         logits.block_until_ready()
     t_prefill = time.perf_counter() - t0
+    finite = jnp.all(jnp.isfinite(logits))
 
     if prefill_stats is not None:
         with tracer.span("telemetry flush"):
@@ -134,12 +135,15 @@ def main(argv=None):
                              "decode step (compile)", pos=pos0 + i):
                 pos = jnp.full((args.batch,), pos0 + i, jnp.int32)
                 logits, caches = decode(params, quant, tok, pos, caches)
+                finite = finite & jnp.all(jnp.isfinite(logits))
                 tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
                 if tracer.enabled:  # fence per-span only when tracing
                     tok.block_until_ready()
             out_tokens.append(tok)
     tok.block_until_ready()
     t_decode = time.perf_counter() - t0
+    if not bool(finite):
+        raise RuntimeError("[serve] non-finite logits in prefill or decode")
 
     gen = jnp.concatenate(out_tokens, axis=1)
     print(f"[serve] arch={cfg.name} policy={args.policy} "
@@ -149,6 +153,7 @@ def main(argv=None):
     print(f"[serve] decode  {args.gen - 1} steps: {t_decode*1e3:.1f} ms "
           f"({(args.gen - 1) * args.batch / max(t_decode, 1e-9):.1f} tok/s)")
     print(f"[serve] sample tokens[0]: {gen[0][:12].tolist()}")
+    print(f"[serve] logits finite in prefill and {args.gen - 1} decode steps")
     if args.trace:
         tracer.export(args.trace)
         print(f"[serve] trace: {args.trace} — load at "

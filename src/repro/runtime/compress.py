@@ -32,19 +32,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-    _REP_KWARG = "check_vma"
-except ImportError:  # older jax keeps it in experimental (kwarg: check_rep)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KWARG = "check_rep"
 from jax.sharding import PartitionSpec as P
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    kw = {_REP_KWARG: check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
 
 from repro.core import estimators, quant
 from repro.core.quant import QuantSpec
@@ -143,7 +131,7 @@ def make_compressor(mesh, dp_axes: tuple, momentum: float = 0.9):
                         *((None,) * (x.ndim - 1))), stacked_grads)
         rep_g = jax.tree_util.tree_map(
             lambda x: P(*((None,) * (x.ndim - 1))), stacked_grads)
-        fn = shard_map(
+        fn = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(specs_g,
                       jax.tree_util.tree_map(lambda _: P(None), state), P()),

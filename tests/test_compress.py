@@ -1,5 +1,6 @@
 """In-hindsight int8 gradient collective: correctness + unbiasedness
 (subprocess with 8 host devices)."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -55,7 +56,6 @@ _SUBPROC = textwrap.dedent("""
 def test_compressed_psum_correct_and_unbiased():
     r = subprocess.run([sys.executable, "-c", _SUBPROC],
                        capture_output=True, text=True, timeout=600,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "PYTHONPATH": "src"})
     assert r.returncode == 0, r.stderr[-3000:]
     assert "COMPRESS_OK" in r.stdout

@@ -75,17 +75,19 @@ def test_plan_conv_validates_geometry():
 
 
 def test_stochastic_on_chip_prng_rejected_in_interpret_mode():
-    """The on-chip PRNG path is TPU-only; interpret mode must keep the
-    deterministic noise-operand form (backend parity depends on it)."""
+    """The on-chip PRNG path is TPU-only; interpret mode (every backend but
+    the TPU, so this CPU run) must keep the deterministic noise-operand
+    form (backend parity depends on it)."""
+    from repro.kernels import platform
+    assert platform.interpret_mode()
     x = _rand((8, 8), 0)
     spec = QuantSpec(bits=8, symmetric=False, stochastic=True)
     with pytest.raises(ValueError, match="real TPU"):
         ops.stochastic_quantize(x, -1.0, 1.0, None, spec=spec,
-                                on_chip_prng=True, seed=3,
-                                interpret=True)
+                                on_chip_prng=True, seed=3)
     with pytest.raises(ValueError, match="seed"):
         from repro.kernels.stochastic_quantize import (
             stochastic_quantize_kernel,
         )
         stochastic_quantize_kernel(x, jnp.ones((1, 2)), None, spec=spec,
-                                   on_chip_prng=True, interpret=False)
+                                   on_chip_prng=True)

@@ -1,6 +1,6 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype/spec sweeps (bit-exact
-integer outputs, fp32-tolerance statistics).  interpret=True executes the
-kernel bodies on CPU."""
+integer outputs, fp32-tolerance statistics).  Off the TPU the kernels run
+in interpret mode, which executes the kernel bodies on CPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -81,6 +81,22 @@ def test_int8_matmul_block_invariance(block):
     base = ops.int8_matmul_fused(xq, wq, 0.02, 117.0, 0.01, None, -4.0, 4.0,
                                  block=(32, 32, 32))
     np.testing.assert_array_equal(np.asarray(out[0]), np.asarray(base[0]))
+
+
+def test_stats_tile_roundtrip():
+    """Each kernel's per-step statistics tile carries statistic r in row r
+    of every lane; the wrappers read them back exactly."""
+    from repro.kernels import STATS_TILE, stats_tile, stats_values
+    vals = [jnp.float32(v) for v in (-3.5, 7.25, 0.0, 1e-30, 3e38, -0.0)]
+    tile = stats_tile(*vals)
+    assert tile.shape == STATS_TILE == (8, 128)
+    for r, v in enumerate(vals):
+        np.testing.assert_array_equal(np.asarray(tile[r]),
+                                      np.full(128, v, np.float32))
+    np.testing.assert_array_equal(np.asarray(tile[len(vals):]), 0.0)
+    np.testing.assert_array_equal(
+        np.asarray(stats_values(tile[None], len(vals))),
+        np.asarray(jnp.stack(vals))[None])
 
 
 def test_kernel_quant_matches_core_quantizer():

@@ -3,6 +3,7 @@
 The subprocess sets ``--xla_force_host_platform_device_count`` BEFORE
 importing jax (this test process must keep seeing 1 device).
 """
+import os
 import subprocess
 import sys
 import textwrap
@@ -75,7 +76,9 @@ _SUBPROC = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     cfg = configs.get_reduced("qwen2-moe-a2.7b")
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    # Auto axes: the model places activations with sharding constraints.
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     opt = adamw()
     state = steps.init_train_state(jax.random.PRNGKey(0), cfg, opt)
     import repro.data as data
@@ -107,7 +110,6 @@ def test_spmd_train_step_matches_single_device(tmp_path):
     single-device run (MoE arch: exercises EP + dispatch sharding)."""
     r = subprocess.run([sys.executable, "-c", _SUBPROC],
                        capture_output=True, text=True, timeout=900,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "PYTHONPATH": "src"})
     assert r.returncode == 0, r.stderr[-3000:]
     assert "SPMD_OK" in r.stdout

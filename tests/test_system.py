@@ -41,6 +41,41 @@ def test_serve_driver_runs(capsys):
     assert "prefill" in out and "tok/s" in out
 
 
+def test_serve_fails_when_restore_fails(tmp_path):
+    """A --ckpt-dir that cannot be restored ends serving with an error
+    instead of serving random weights."""
+    with pytest.raises(SystemExit) as e:
+        serve_mod.main(["--arch", "starcoder2-3b", "--reduced", "--batch",
+                        "2", "--prompt-len", "16", "--gen", "4",
+                        "--ckpt-dir", str(tmp_path / "empty")])
+    assert e.value.code not in (0, None)
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_location(tmp_path, monkeypatch, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR wins and nothing else is set; otherwise
+    the cache sits at the fixed <checkout>/.jax_cache."""
+    from repro.launch import compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    sentinel = str(tmp_path / "unchanged")
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                ".jax_cache")
+            assert compile_cache.enable() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        else:
+            given = str(tmp_path / env_dir)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", given)
+            assert compile_cache.enable() == given
+            assert jax.config.jax_compilation_cache_dir == sentinel
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_serve_int8_cache(capsys):
     serve_mod.main(["--arch", "starcoder2-3b", "--reduced", "--batch", "2",
                     "--prompt-len", "16", "--gen", "4", "--int8-cache"])
