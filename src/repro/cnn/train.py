@@ -8,11 +8,10 @@ range semantics shared with the LM path.
 Also runnable as a driver (parity with ``repro.launch.train``):
 
   PYTHONPATH=src python -m repro.cnn.train --arch mobilenetv2 \
-      --steps 50 --batch 16 --policy hindsight --backend fused
+      --steps 50 --batch 16 --policy hindsight --backend fused \
+      --trace /tmp/cnn-trace
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +20,7 @@ from repro.core import qlinear
 from repro.core.policy import QuantPolicy
 from repro.data import ImageStream
 from repro.optim import apply_updates, clip_by_global_norm, sgdm
+from repro.telemetry import trace
 
 from . import models
 
@@ -64,29 +64,25 @@ def calibrate_cnn(cfg, params, bn, quant, policy, stream: ImageStream,
         _, (_, stats, _) = models.loss_fn(cfg, params, bn, q, batch, obs,
                                           0, 0, train=False)
         return stats
-
-    for i in range(batches):
-        stats = fwd(quant, stream.batch(10_000 + i))
-        quant = qlinear.update_quant_state(obs, quant, stats)
+    with trace.span("cnn.calibrate", batches=batches):
+        for i in range(batches):
+            s = fwd(quant, stream.batch(10_000 + i))
+            quant = qlinear.update_quant_state(obs, quant, s)
     return quant
 
 
 def train_cnn(cfg: models.CNNConfig, policy: QuantPolicy, *, steps: int,
               batch: int, lr: float = 0.05, seed: int = 0,
               calibration_batches: int = 2, eval_batches: int = 4,
-              lr_schedule=None, telemetry_sink=None,
-              trace_path: Optional[str] = None):
+              lr_schedule=None, telemetry_sink=None):
     """Train + eval; returns (final_eval_acc, history).
 
     ``telemetry_sink``: any object with ``write(step, records)`` (e.g.
     ``repro.telemetry.JsonlSink`` / ``MemorySink``); fed the per-site
     health records collected from the quant state after every step when
     the policy has telemetry enabled.  When a sink is armed, each line
-    also carries the step's ``"perf"`` phase breakdown.
-
-    ``trace_path``: export a Chrome-trace JSON of the step phases
-    (data / compile / execute / telemetry) to this path — host-side
-    timing only, the computation is unchanged."""
+    also carries the step's ``"perf"`` phase breakdown.  The step phases
+    are ``repro/*`` spans in any live profiler session (``--trace``)."""
     from repro.optim.schedules import cosine
     key = jax.random.PRNGKey(seed)
     params, bn = models.init(key, cfg)
@@ -108,9 +104,7 @@ def train_cnn(cfg: models.CNNConfig, policy: QuantPolicy, *, steps: int,
     if telemetry_sink is not None and policy.telemetry.enabled:
         from repro.telemetry import collect
 
-    from repro.telemetry import trace as trace_mod
-    tracer = trace_mod.Tracer(enabled=bool(trace_path))
-    timer = trace_mod.StepTimer(tracer)
+    timer = trace.StepTimer()
 
     history = []
     for s in range(steps):
@@ -128,8 +122,6 @@ def train_cnn(cfg: models.CNNConfig, policy: QuantPolicy, *, steps: int,
             telemetry_sink.write(
                 s, records, perf=timer.perf_record(items=batch,
                                                    unit="images"))
-    if trace_path:
-        tracer.export(trace_path)
 
     @jax.jit
     def eval_fn(state, batch):
@@ -180,9 +172,11 @@ def main(argv=None):
                          "in the cwd)")
     ap.add_argument("--guard", action="store_true",
                     help="arm the overflow guard (implies --telemetry)")
-    ap.add_argument("--trace", default="", metavar="PATH",
-                    help="export a Chrome-trace JSON of the step phases "
-                         "to PATH (view at https://ui.perfetto.dev)")
+    ap.add_argument("--trace", default="", metavar="DIR",
+                    help="run a jax.profiler session over the run, written "
+                         "to DIR: its perfetto_trace.json.gz holds the "
+                         "step-phase spans and the device's operations on "
+                         "one clock (view at https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
     compile_cache.enable()
     if args.guard:
@@ -207,12 +201,14 @@ def main(argv=None):
     if args.telemetry:
         sink = telemetry.JsonlSink(args.telemetry_out or "telemetry.jsonl")
         print(f"[cnn.train] telemetry -> {sink.path}")
-    acc, history = train_cnn(
-        cfg, policy, steps=args.steps, batch=args.batch, lr=args.lr,
-        seed=args.seed, calibration_batches=args.calibration_batches,
-        telemetry_sink=sink, trace_path=args.trace or None)
+    with trace.session(args.trace):
+        acc, history = train_cnn(
+            cfg, policy, steps=args.steps, batch=args.batch, lr=args.lr,
+            seed=args.seed, calibration_batches=args.calibration_batches,
+            telemetry_sink=sink)
     if args.trace:
-        print(f"[cnn.train] trace: {args.trace} — load at "
+        print(f"[cnn.train] trace: {args.trace} — load its "
+              f"plugins/profile/*/perfetto_trace.json.gz at "
               f"https://ui.perfetto.dev")
     for i, met in enumerate(history):
         if i % 10 == 0 or i == len(history) - 1:

@@ -19,6 +19,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import checkpoint, configs, data, telemetry
 from repro.core.policy import QuantPolicy
@@ -42,13 +43,27 @@ def main(argv=None):
     ap.add_argument("--telemetry", default="",
                     help="write per-site prefill quantization health "
                          "(clip/SQNR/util) as JSONL to this path")
-    ap.add_argument("--trace", default="", metavar="PATH",
-                    help="export a Chrome-trace JSON of the serving "
-                         "phases (prefill / per-step decode / telemetry) "
-                         "to PATH — view at https://ui.perfetto.dev")
+    ap.add_argument("--trace", default="", metavar="DIR",
+                    help="run a jax.profiler session over the run, written "
+                         "to DIR: its perfetto_trace.json.gz holds the "
+                         "prefill / per-token decode / fetch spans and the "
+                         "device's operations on one clock (view at "
+                         "https://ui.perfetto.dev)")
     args = ap.parse_args(argv)
+    with telemetry.trace.session(args.trace):
+        gen = run(args)
+    if args.trace:
+        print(f"[serve] trace: {args.trace} — load its "
+              f"plugins/profile/*/perfetto_trace.json.gz at "
+              f"https://ui.perfetto.dev")
+    return gen
+
+
+def run(args):
+    """Serve one batched request as ``args`` say; returns its tokens."""
     compile_cache.enable()
-    tracer = telemetry.Tracer(enabled=bool(args.trace))
+    span = telemetry.trace.span
+    request = 0                    # the index of the (one) request served
 
     cfg = configs.get_reduced(args.arch) if args.reduced \
         else configs.get(args.arch)
@@ -103,11 +118,10 @@ def main(argv=None):
         p, q, t, pos, c, cfg, policy), donate_argnums=(4,))
 
     t0 = time.perf_counter()
-    # The first prefill/decode call compiles — the trace shows it as one
-    # long "prefill (compile+execute)" span, the decode steps as a span
-    # per generated token.
-    with tracer.span("prefill (compile+execute)", batch=args.batch,
-                     prompt_len=args.prompt_len):
+    # The first prefill/decode call compiles: in the trace, under the first
+    # serve.prefill and serve.decode spans.
+    with span("serve.prefill", request=request, batch=args.batch,
+              prompt_len=args.prompt_len):
         if want_stats:
             logits, caches, prefill_stats = prefill(params, quant, prompt)
         else:
@@ -118,7 +132,7 @@ def main(argv=None):
     finite = jnp.all(jnp.isfinite(logits))
 
     if prefill_stats is not None:
-        with tracer.span("telemetry flush"):
+        with span("serve.telemetry", request=request):
             sink = telemetry.JsonlSink(args.telemetry, max_steps=1024)
             sink.write(0, telemetry.collect(prefill_stats))
             sink.close()
@@ -129,17 +143,16 @@ def main(argv=None):
     tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
     out_tokens = [tok]
     t0 = time.perf_counter()
-    with tracer.span("decode", steps=args.gen - 1):
-        for i in range(args.gen - 1):
-            with tracer.span("decode step" if i else
-                             "decode step (compile)", pos=pos0 + i):
-                pos = jnp.full((args.batch,), pos0 + i, jnp.int32)
-                logits, caches = decode(params, quant, tok, pos, caches)
-                finite = finite & jnp.all(jnp.isfinite(logits))
-                tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-                if tracer.enabled:  # fence per-span only when tracing
-                    tok.block_until_ready()
-            out_tokens.append(tok)
+    for i in range(args.gen - 1):
+        with span("serve.decode", request=request, pos=pos0 + i):
+            pos = jnp.full((args.batch,), pos0 + i, jnp.int32)
+            logits, caches = decode(params, quant, tok, pos, caches)
+            finite = finite & jnp.all(jnp.isfinite(logits))
+            tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
+        if args.trace:  # fetch (and so fence) per token only when tracing
+            with span("serve.fetch", request=request, pos=pos0 + i):
+                np.asarray(tok)
+        out_tokens.append(tok)
     tok.block_until_ready()
     t_decode = time.perf_counter() - t0
     if not bool(finite):
@@ -154,10 +167,6 @@ def main(argv=None):
           f"({(args.gen - 1) * args.batch / max(t_decode, 1e-9):.1f} tok/s)")
     print(f"[serve] sample tokens[0]: {gen[0][:12].tolist()}")
     print(f"[serve] logits finite in prefill and {args.gen - 1} decode steps")
-    if args.trace:
-        tracer.export(args.trace)
-        print(f"[serve] trace: {args.trace} — load at "
-              f"https://ui.perfetto.dev")
     return gen
 
 

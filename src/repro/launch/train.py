@@ -12,11 +12,12 @@ deployment needs):
     (> --straggler-factor x trailing median) — on a real cluster this is
     the signal that triggers hot-spare swap / elastic down-scale,
   * metrics JSONL log for the benchmark harness,
-  * performance observability (--trace): every step is split into
+  * performance observability (--trace DIR): every step is split into
     data / compile / execute / telemetry / checkpoint phases by a
-    repro.telemetry.trace.StepTimer; the span timeline exports as
-    Chrome-trace JSON (load it at https://ui.perfetto.dev) and each
-    step's phase breakdown rides the telemetry JSONL stream as a
+    repro.telemetry.trace.StepTimer; --trace runs a jax.profiler session
+    over the run, whose Perfetto file holds those spans and the device's
+    operations on one clock (load it at https://ui.perfetto.dev), and
+    each step's phase breakdown rides the telemetry JSONL stream as a
     "perf" record (render with `repro.telemetry.report --perf`).
 
 Examples:
@@ -72,15 +73,21 @@ class Watchdog:
         self.window = window
         self.flagged = 0
 
-    def step(self, dt: float, step: int):
+    def step(self, dt: float, step: int, counters=None):
+        """``counters``: the step's growth of
+        ``repro.telemetry.trace.counters()``; a straggler's message names
+        the compiles and collections that ran in it."""
         hist = self.durations[-self.window:]
         if len(hist) >= 8:
             med = statistics.median(hist)
             if dt > self.factor * med:
                 self.flagged += 1
+                host = ", ".join(f"{k} {v:.4g}" for k, v in
+                                 (counters or {}).items() if v)
                 print(f"[watchdog] step {step}: {dt*1e3:.0f}ms "
                       f"(median {med*1e3:.0f}ms) — straggler suspected; "
-                      f"a production deployment would alert the scheduler")
+                      f"a production deployment would alert the scheduler"
+                      + (f" (in the step: {host})" if host else ""))
         self.durations.append(dt)
 
 
@@ -149,12 +156,13 @@ def parse_args(argv=None):
                     help="range expansion factor in widen mode")
     ap.add_argument("--guard-mode", default="widen",
                     choices=list(telemetry.GUARD_MODES))
-    ap.add_argument("--trace", default="", metavar="PATH",
-                    help="export a Chrome-trace JSON of the step phases "
-                         "(data/compile/execute/telemetry/checkpoint) to "
-                         "PATH — viewable at https://ui.perfetto.dev; "
-                         "tracing is host-side only and never changes the "
-                         "computation")
+    ap.add_argument("--trace", default="", metavar="DIR",
+                    help="run a jax.profiler session over the run, written "
+                         "to DIR: its perfetto_trace.json.gz holds the "
+                         "step-phase spans (repro/*) and the device's "
+                         "operations on one clock — viewable at "
+                         "https://ui.perfetto.dev; the computation never "
+                         "changes")
     args = ap.parse_args(argv)
     if args.guard:
         args.telemetry = True
@@ -163,6 +171,17 @@ def parse_args(argv=None):
 
 def main(argv=None):
     args = parse_args(argv)
+    with telemetry.trace.session(args.trace):
+        state = run(args)
+    if args.trace:
+        print(f"[train] trace: {args.trace} — load its "
+              f"plugins/profile/*/perfetto_trace.json.gz at "
+              f"https://ui.perfetto.dev")
+    return state
+
+
+def run(args):
+    """Train as ``args`` say; returns the final state."""
     compile_cache.enable()
     cfg = configs.get_reduced(args.arch) if args.reduced \
         else configs.get(args.arch)
@@ -218,8 +237,7 @@ def main(argv=None):
               f"(guard={'on' if policy.telemetry.guard else 'off'}, "
               f"mode={policy.telemetry.mode})")
 
-    tracer = telemetry.Tracer(enabled=bool(args.trace))
-    timer = telemetry.StepTimer(tracer)
+    timer = telemetry.StepTimer()
     tokens_per_step = args.batch * args.seq
 
     for step in range(start, args.steps):
@@ -236,7 +254,8 @@ def main(argv=None):
                     records = telemetry.collect(state["quant"])
                     events = tele_events.update(step, records)
                 for ev in events:
-                    tracer.instant(f"guard:{ev['action']}", site=ev["site"])
+                    telemetry.trace.instant(f"guard:{ev['action']}",
+                                            site=ev["site"])
                     print(f"[guard] step {step}: {ev['action']} @ "
                           f"{ev['site']} {ev['old']} -> {ev['new']} "
                           f"(clip {100 * ev['clip_rate']:.2f}%)")
@@ -254,7 +273,7 @@ def main(argv=None):
         phases = timer.last["phases"]
         dt = (phases.get("data", 0.0) + phases.get("compile", 0.0)
               + phases.get("execute", 0.0)) / 1e3
-        wd.step(dt, step)
+        wd.step(dt, step, timer.last["counters"])
 
         if step % args.log_every == 0 or step == args.steps - 1:
             print(f"[train] step {step:5d} loss {met['loss']:.4f} "
@@ -279,10 +298,6 @@ def main(argv=None):
         print(f"[train] telemetry log: {tele_sink.path} — render with "
               f"`python -m repro.telemetry.report {tele_sink.path}` "
               f"(--perf for the step-phase breakdown)")
-    if args.trace:
-        tracer.export(args.trace)
-        print(f"[train] trace: {args.trace} — load at "
-              f"https://ui.perfetto.dev (or chrome://tracing)")
     return state
 
 

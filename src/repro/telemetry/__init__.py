@@ -21,9 +21,10 @@ Layers:
   * :mod:`repro.telemetry.sinks`   — host-side ``collect`` + bounded
     JSONL ring writer and in-memory aggregator.
   * :mod:`repro.telemetry.trace`   — host-side performance tracing:
-    ``Tracer`` spans exporting Chrome-trace JSON (Perfetto-viewable),
-    ``StepTimer`` step-phase breakdown (data / compile / execute /
-    telemetry / checkpoint) and the ``"perf"`` JSONL record builder.
+    ``repro/*`` spans on the profiler's clock, the ``--trace DIR``
+    profiler session, ``StepTimer`` step-phase breakdown (data / compile
+    / execute / telemetry / checkpoint) with the ``"perf"`` JSONL record
+    builder, and process-wide compile and GC counters.
   * :mod:`repro.telemetry.report`  — ``python -m repro.telemetry.report``
     per-site health tables (and ``--perf`` per-phase time tables) from
     a JSONL log.
@@ -54,5 +55,5 @@ from .sinks import (  # noqa: F401
     read_jsonl_full,
     read_jsonl_records,
 )
-from .trace import StepTimer, Tracer  # noqa: F401
+from .trace import StepTimer  # noqa: F401
 from . import trace  # noqa: F401

@@ -181,7 +181,7 @@ def main(argv=None):
             ap.error(f"cannot read {args.log}: {e}")
         if perf is None:
             print(f"[report] no perf records in {args.log} (run the "
-                  f"trainer with --trace / a StepTimer to produce them)")
+                  f"trainer with --telemetry to produce them)")
             return None
         if args.json:
             payload = {k: v for k, v in perf.items() if k != "records"}

@@ -26,8 +26,8 @@ version-less lines are schema v1 and still parse):
                  "clip_rate": f, "streak": f}, ...],
      "perf": {"step_time_ms": f, "phases_ms": {"data": f, "compile": f,
               "execute": f, "telemetry": f, "checkpoint": f},
-              "compile_count": i, "throughput": f,
-              "throughput_unit": "tokens/s"|"images/s"}}
+              "compile_count": i, "counters": {<trace.KEYS>: f, ...},
+              "throughput": f, "throughput_unit": "tokens/s"|"images/s"}}
 
 ``events`` (present only when non-empty) are the EXPLICIT guard-trigger
 records produced by :class:`repro.telemetry.events.GuardEventDetector` —

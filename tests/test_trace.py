@@ -1,8 +1,11 @@
-"""Performance observability layer: span tracer / Chrome-trace export,
-StepTimer phase accounting, "perf" JSONL schema round-trip + backward
+"""Performance observability layer: program spans in a profiler session,
+compile and GC counters, StepTimer phase accounting, "perf" JSONL schema round-trip + backward
 compatibility, report --perf rendering, and the benchmark regression
 gate."""
+import gc
+import glob
 import json
+import os
 import time
 
 import jax
@@ -21,51 +24,164 @@ from repro.telemetry.sinks import (
 
 
 # ---------------------------------------------------------------------------
-# Tracer: span nesting + Chrome-trace-event export.
+# Spans on the profiler's clock, and the process-wide counters.
 # ---------------------------------------------------------------------------
-def test_span_export_is_valid_chrome_trace(tmp_path):
-    tr = trace_mod.Tracer()
-    with tr.span("outer", step=3):
-        with tr.span("inner"):
+def _host_events(log_dir):
+    """``[(line, name, start_ns, end_ns, args)]`` of the host planes of the
+    one profiler session written under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    start = int(ev.start_ns)
+                    out.append((line.name, ev.name, start,
+                                start + int(ev.duration_ns),
+                                {k: v for k, v in ev.stats}))
+    return out
+
+
+def _one(events, name):
+    found = [e for e in events if e[1] == name]
+    assert len(found) == 1, (name, [e[1] for e in events])
+    return found[0]
+
+
+def _within(inner, outer):
+    return (inner[0] == outer[0] and outer[2] <= inner[2]
+            and inner[3] <= outer[3])
+
+
+def test_spans_land_in_profiler_session_nested(tmp_path):
+    f = jax.jit(lambda x: (x * 2.0).sum())
+    x = jnp.ones((8, 8))
+    f(x).block_until_ready()                   # compiled before the session
+    timer = trace_mod.StepTimer()
+    with trace_mod.session(str(tmp_path)):
+        with timer.step(4) as st:
+            with st.phase("data"):
+                y = x + 1.0
+            with st.execute():
+                float(f(y))
+    evs = _host_events(tmp_path)
+    step = _one(evs, "repro/train")
+    assert step[4]["step_num"] == 4
+    data, compile_ = _one(evs, "repro/data"), _one(evs, "repro/compile")
+    assert _within(data, step) and _within(compile_, step)
+    # the runtime's dispatch of the jitted call, named after the function,
+    # lies inside the program's span around it, on the same thread
+    pjit = [e for e in evs if e[1].startswith("PjitFunction(")
+            and _within(e, compile_)]
+    assert pjit, sorted({e[1] for e in evs})
+    # the operator's file: the same session as a Perfetto trace
+    assert glob.glob(os.path.join(str(tmp_path), "**",
+                                  "perfetto_trace.json.gz"), recursive=True)
+    # the host clock still times the phases
+    assert set(timer.last["phases"]) == {"data", "compile"}
+
+
+def test_spans_without_session_record_nothing(tmp_path):
+    timer = trace_mod.StepTimer()
+    with timer.step(0) as st:                  # no session live
+        with st.phase("data"):
             time.sleep(0.002)
-    path = tr.export(tmp_path / "trace.json")
-    obj = json.load(open(path))
-    evs = obj["traceEvents"]
-    assert [e["name"] for e in evs] == ["outer", "inner"]  # sorted by ts
-    for e in evs:
-        # the Chrome trace-event contract Perfetto parses
-        assert e["ph"] == "X"
-        for field in ("ts", "dur", "pid", "tid", "name"):
-            assert field in e
-    outer, inner = evs
-    # nesting: the inner interval lies within the outer one
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
-    assert inner["dur"] >= 2e3  # slept 2ms -> >= 2000us
-    assert outer["args"] == {"step": 3}
-
-
-def test_disabled_tracer_records_nothing():
-    tr = trace_mod.Tracer(enabled=False)
-    with tr.span("x"):
-        pass
-    tr.instant("y")
-    assert tr.events == []
-
-
-def test_active_tracer_span_helper():
-    tr = trace_mod.Tracer()
-    prev = trace_mod.set_tracer(tr)
-    try:
-        with trace_mod.span("via-active"):
+        with trace_mod.span("unseen", k=1):
             pass
+    assert timer.last["phases"]["data"] >= 2.0
+    assert timer.last["total_ms"] >= timer.last["phases"]["data"]
+    with trace_mod.session(str(tmp_path)):
+        with trace_mod.span("seen"):
+            pass
+    names = {e[1] for e in _host_events(tmp_path)}
+    assert "repro/seen" in names
+    assert not {"repro/unseen", "repro/data", "repro/train"} & names
+    # a false directory opens no session at all
+    with trace_mod.session(""):
+        with trace_mod.span("nowhere"):
+            pass
+
+
+def test_guard_instant_appears_in_session(tmp_path):
+    with trace_mod.session(str(tmp_path)):
+        trace_mod.instant("guard:widen", site="layers/0/act")
+    ev = _one(_host_events(tmp_path), "repro/guard:widen")
+    assert ev[4] == {"site": "layers/0/act"}
+    assert ev[3] - ev[2] < 1e6                 # a point, not a span of work
+
+
+def test_fresh_jit_raises_compile_counters():
+    x = jnp.arange(7.0)
+    inner = jax.jit(lambda v: v * 3.0)
+    outer = jax.jit(lambda v: inner(v).sum() + 1.0)
+    before = trace_mod.counters()
+    float(outer(x))
+    grown = trace_mod.since(before)
+    # one outer tracing (the inner one nested in it counts with it), one
+    # lowering and one compile, each with its seconds
+    assert grown["traces"] == 1 and grown["trace_s"] > 0
+    assert grown["lowerings"] == 1 and grown["lower_s"] > 0
+    assert grown["compiles"] == 1 and grown["compile_s"] > 0
+    again = trace_mod.counters()
+    float(outer(x))                            # cached: nothing grows
+    assert trace_mod.since(again)["compiles"] == 0
+    assert set(trace_mod.counters()) == set(trace_mod.KEYS)
+
+
+def test_counters_hold_under_concurrent_compiles():
+    """Sixteen threads compile at once, with the interpreter switching
+    threads as often as it can: no update of the counters is lost."""
+    import sys
+    import threading
+
+    x = jnp.ones(3)
+    fns = [jax.jit(lambda v, k=k: v * float(k)) for k in range(16)]
+    before = trace_mod.counters()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=lambda f=f: f(x).block_until_ready())
+                   for f in fns]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
     finally:
-        trace_mod.set_tracer(prev)
-    assert [e["name"] for e in tr.events] == ["via-active"]
-    # after restore, the module-level helper is a no-op again
-    with trace_mod.span("dropped"):
-        pass
-    assert len(tr.events) == 1
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    grown = trace_mod.since(before)
+    assert grown["traces"] == 16 and grown["compiles"] == 16
+
+
+def test_gc_collect_raises_gc_counters_and_span(tmp_path):
+    before = trace_mod.counters()
+    with trace_mod.session(str(tmp_path)):
+        gc.collect()
+    grown = trace_mod.since(before)
+    assert grown["gc_gen2"] >= 1 and grown["gc_pause_s"] > 0
+    gcs = [e for e in _host_events(tmp_path) if e[1] == "repro/gc"]
+    assert any(e[4] == {"generation": 2} for e in gcs)
+
+
+def test_perf_record_and_straggler_name_the_step_counters(capsys):
+    from repro.launch.train import Watchdog
+
+    timer = trace_mod.StepTimer()
+    with timer.step(0) as st:
+        with st.execute():
+            gc.collect()
+    perf = timer.perf_record()
+    assert perf["counters"]["gc_gen2"] >= 1
+    assert all(v for v in perf["counters"].values())
+    wd = Watchdog(factor=3.0)
+    for s in range(8):
+        wd.step(0.01, s)
+    wd.step(1.0, 8, timer.last["counters"])
+    out = capsys.readouterr().out
+    assert wd.flagged == 1 and "straggler" in out and "gc_gen2 1" in out
 
 
 # ---------------------------------------------------------------------------
