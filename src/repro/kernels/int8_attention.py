@@ -48,6 +48,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -79,7 +80,7 @@ class AttnSchedule:
     window: int            # sliding window (0 when unused)
     prefix_len: int        # prefix-LM boundary (0 when unused)
     sm_scale: float        # softmax scale (head_dim ** -0.5)
-    width: int             # kv blocks visited per q block (the schedule)
+    width: int             # kv blocks walked per q block (the grid's last axis)
 
     @property
     def nq(self) -> int:
@@ -89,17 +90,26 @@ class AttnSchedule:
     def nkv(self) -> int:
         return -(-self.skv // self.bkv)
 
+    @property
+    def visited_blocks(self) -> int:
+        """(q block, kv block) tiles computed, of the ``nq * width`` grid
+        steps: the rest are skipped as fully masked."""
+        i = np.arange(self.nq)[:, None]
+        ki = _kv_block_base(i, self, np) + np.arange(self.width)[None, :]
+        vis = _block_visited(i, ki, self, np)
+        return self.nq * self.width if vis is None else int(vis.sum())
+
 
 def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
                   groups: int, mode: str, window: int = 0,
                   prefix_len: int = 0, sm_scale: float) -> AttnSchedule:
     """Resolve block sizes and the per-q-block kv visitation width.
 
-    For every mode but ``sliding`` each q block walks all kv blocks (the
-    block-level ``visited`` predicate then skips the fully-masked ones).
+    For every mode but ``sliding`` each q block walks all kv blocks.
     For ``sliding`` the width is the *block-local fast path*: the maximum
-    number of kv blocks any q block's window can touch — O(S * w) total
-    work instead of O(S^2).
+    number of kv blocks any q block's window can touch — O(S * w) grid
+    steps instead of O(S^2).  In every mode the block-level ``visited``
+    predicate then skips the walk's fully-masked blocks.
     """
     if mode not in MASK_MODES:
         raise ValueError(f"unknown mask mode {mode!r}; expected {MASK_MODES}")
@@ -128,29 +138,55 @@ def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
                         sm_scale=float(sm_scale), width=width)
 
 
-def _kv_block_base(i, sched: AttnSchedule):
-    """First kv block index q block ``i`` visits (traced-int arithmetic:
-    also used inside BlockSpec index maps)."""
+def _kv_block_span(i, sched: AttnSchedule, xp=jnp):
+    """``(first, last)`` kv block holding an attended pair for some row of q
+    block ``i``, or None where every block may (cross, bidir).
+
+    The blocks in between all hold one, so the span is the exact set of
+    visited blocks.  Traced-int arithmetic (``xp=jnp``: also used inside
+    BlockSpec index maps) or static (``xp=numpy``).
+    """
+    S = sched
+    if S.mode in ("cross", "bidir"):
+        return None
+    last = (i * S.bq + S.bq - 1) // S.bkv         # causal half
+    first = i * 0
+    if S.mode == "prefix":
+        last = xp.maximum(last, (S.prefix_len - 1) // S.bkv)
+    elif S.mode == "sliding":                     # window half
+        first = xp.maximum(i * S.bq - S.window + 1, 0) // S.bkv
+    return first, xp.minimum(last, S.nkv - 1)
+
+
+def _kv_block_base(i, sched: AttnSchedule, xp=jnp):
+    """First kv block index q block ``i`` walks from."""
     if sched.mode != "sliding" or sched.width >= sched.nkv:
         return i * 0
-    hi = jnp.minimum((i * sched.bq + sched.bq - 1) // sched.bkv,
-                     sched.nkv - 1)
-    return jnp.clip(hi - (sched.width - 1), 0, max(sched.nkv - sched.width, 0))
+    hi = _kv_block_span(i, sched, xp)[1]
+    return xp.clip(hi - (sched.width - 1), 0, max(sched.nkv - sched.width, 0))
 
 
-def _block_visited(i, ki, sched: AttnSchedule):
+def _kv_block_index(i, t, sched: AttnSchedule):
+    """kv block read at step ``t`` of q block ``i``: the walk's block,
+    clamped into the visited span so that a skipped step presents the
+    block of the nearest visited one and the pipeline issues no DMA."""
+    ki = _kv_block_base(i, sched) + t
+    span = _kv_block_span(i, sched)
+    return ki if span is None else jnp.clip(ki, *span)
+
+
+def _block_visited(i, ki, sched: AttnSchedule, xp=jnp):
     """Block-level skip predicate (None = statically always visited).
 
     A skipped block is PROVABLY fully masked for every row of the q
     block, so skipping it is exact: the reference applies the same
     predicate with ``where(visited, new, old)`` on its carries.
     """
-    if sched.mode in ("cross", "bidir", "sliding"):
+    span = _kv_block_span(i, sched, xp)
+    if span is None:
         return None
-    causal = (ki * sched.bkv) <= (i * sched.bq + sched.bq - 1)
-    if sched.mode == "prefix":
-        return jnp.logical_or(causal, (ki * sched.bkv) < sched.prefix_len)
-    return causal
+    first, last = span
+    return (ki >= first) & (ki <= last)
 
 
 def _element_mask(q_pos, k_pos, kvlen, sched: AttnSchedule):
@@ -407,10 +443,10 @@ def attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, *, sched: AttnSchedule):
     ksum = jnp.sum(k_i8.astype(jnp.int32), axis=-1)[:, None, :]   # [ZB,1,skv]
 
     def kvmap(b, i, t):
-        return (b // g, _kv_block_base(i, S) + t, 0)
+        return (b // g, _kv_block_index(i, t, S), 0)
 
     def ksum_map(b, i, t):
-        return (b // g, 0, _kv_block_base(i, S) + t)
+        return (b // g, 0, _kv_block_index(i, t, S))
 
     out, ml, ps = pl.pallas_call(
         functools.partial(_attn_kernel, sched=S),

@@ -11,7 +11,8 @@ Also covered here:
     standalone ``tensor_minmax`` passes on the attention sites),
   * ragged (non-block-multiple) shapes and runtime kv_len bounds,
   * fully-masked rows stay NaN-free in forward AND backward,
-  * the sliding-window block-local fast path (grid width < nkv),
+  * the sliding-window block-local fast path (grid width < nkv) and the
+    skip of fully masked tiles, counted by ``AttnSchedule.visited_blocks``,
   * probability-site clip/SQNR counters and the widen guard,
   * ``qattn_int8_*`` / ``k_attn_*`` named scopes in compiled HLO,
   * the fused jitted train step never materializes the full fp score
@@ -27,6 +28,7 @@ import pytest
 from repro.core import backend, qlinear, quant
 from repro.core.policy import QuantPolicy
 from repro.core.state import make_range_state
+from repro.kernels import int8_attention as ia
 from repro.kernels import tuning
 from repro.kernels.int8_attention import make_schedule
 from repro.launch import hlo_cost
@@ -218,6 +220,97 @@ def test_sliding_window_narrows_the_grid():
     full = make_schedule(sq=256, skv=256, hd=64, bq=64, bkv=64, groups=1,
                          mode="causal", sm_scale=0.125)
     assert full.width == 4
+
+
+def _dense_attend(sq, skv, mode, window):
+    q = np.arange(sq)[:, None]
+    k = np.arange(skv)[None, :]
+    if mode == "bidir":
+        return np.ones((sq, skv), bool)
+    m = k <= q
+    return m & (q - k < window) if mode == "sliding" else m
+
+
+@pytest.mark.parametrize("seq,block,mode,window,visited", [
+    (4096, 128, "causal", 0, 528),        # starcoder2-3b at seq 4096
+    (4096, 128, "sliding", 4096, 528),    # its window covers the sequence
+    (4096, 128, "bidir", 0, 1024),
+    (256, 64, "sliding", 64, 7),          # block-local walk, width 2 of 4
+    (256, 64, "sliding", 1000, 10),
+])
+def test_visited_blocks(seq, block, mode, window, visited):
+    """The static count of computed tiles is the count of tiles holding at
+    least one attended pair: every other tile is skipped."""
+    sched = make_schedule(sq=seq, skv=seq, hd=64, bq=block, bkv=block,
+                          groups=1, mode=mode, window=window,
+                          sm_scale=0.125)
+    n = seq // block
+    tiles = _dense_attend(seq, seq, mode, window).reshape(
+        n, block, n, block).any(axis=(1, 3))
+    assert sched.visited_blocks == int(tiles.sum()) == visited
+
+
+def _core_inputs(seq, bh, zb, seed=0):
+    """Random on-grid core operands with realistic quant registers."""
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.randint(kq, (bh, seq, HD), 0, 256).astype(jnp.uint8)
+    k = jax.random.randint(kk, (zb, seq, HD), -127, 128).astype(jnp.int8)
+    v = jax.random.randint(kv, (zb, seq, HD), -127, 128).astype(jnp.int8)
+    scale_p = 1.0 / 255.0
+    regs = jnp.array([[128.0, 0.02 * HD ** -0.5, scale_p, 0.0,
+                       scale_p * 0.01, 0.0, 1.0, 0.0]], jnp.float32)
+    kvlen = jnp.full((1, 1), seq, jnp.int32)
+    return q, k, v, regs, kvlen
+
+
+def _core_both(sched, args):
+    """(kernel, reference) results of one core call, each freshly traced."""
+    return (jax.jit(lambda *a: ia.attention_kernel(*a, sched=sched))(*args),
+            jax.jit(lambda *a: ia.attention_core_reference(
+                *a, sched=sched))(*args))
+
+
+def _assert_core_equal(a, b, what):
+    for name, x, y in zip(("out", "ml", "pstats"), a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f"{what}: {name}")
+
+
+def test_sliding_window_past_sequence_is_causal():
+    """A window that covers the sequence skips the same fully masked tiles
+    as the causal mask: kernel and reference agree bit for bit, and both
+    equal the causal call (outputs, softmax residuals, all statistics)."""
+    seq, g = 24, 2
+    args = _core_inputs(seq, bh=2 * g, zb=2)
+    kw = dict(sq=seq, skv=seq, hd=HD, bq=8, bkv=8, groups=g, sm_scale=1.0)
+    sliding = make_schedule(mode="sliding", window=seq + 5, **kw)
+    causal = make_schedule(mode="causal", **kw)
+    assert sliding.width == causal.width == 3
+    assert sliding.visited_blocks == causal.visited_blocks == 6
+    fus, sim = _core_both(sliding, args)
+    _assert_core_equal(fus, sim, "sliding fused vs simulated")
+    _assert_core_equal(fus, _core_both(causal, args)[0], "sliding vs causal")
+
+
+def test_sliding_skip_outside_window_is_exact(monkeypatch):
+    """Block-local walk (width 2 of 3 kv blocks) where q block 3 (rows
+    24..31) starts its walk at kv block 0 (keys 0..15), wholly outside its
+    window of 8: the block is skipped, kernel and reference agree bit for
+    bit, and outputs equal a run that computes every walked tile."""
+    seq, g = 48, 2
+    args = _core_inputs(seq, bh=2 * g, zb=2, seed=3)
+    sched = make_schedule(sq=seq, skv=seq, hd=HD, bq=8, bkv=16, groups=g,
+                          mode="sliding", window=8, sm_scale=1.0)
+    assert (sched.width, sched.nkv) == (2, 3)
+    assert sched.visited_blocks == 8 < sched.nq * sched.width
+    assert not ia._block_visited(3, 0, sched)
+    skip = _core_both(sched, args)
+    _assert_core_equal(*skip, "skipped: fused vs simulated")
+    monkeypatch.setattr(ia, "_block_visited", lambda *a, **k: None)
+    for every in _core_both(sched, args):
+        for name, x, y in zip(("out", "ml"), skip[0], every):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                          err_msg=f"every tile: {name}")
 
 
 # ---------------------------------------------------------------------------

@@ -107,12 +107,11 @@ def test_int8_matmul_fused_compiles(chip):
         chip((D_FF,), "float32"))
 
 
-@pytest.mark.parametrize("block", [None, *tuning.ATTN_CANDIDATES])
-def test_int8_attention_compiles(chip, block):
+def _compile_attention(chip, block, mode, window):
     bq, bkv = block or tuning.attention_block(SEQ, SEQ, HEAD_DIM)
     sched = int8_attention.make_schedule(
         sq=SEQ, skv=SEQ, hd=HEAD_DIM, bq=bq, bkv=bkv, groups=GROUPS,
-        mode="sliding", window=WINDOW, sm_scale=HEAD_DIM ** -0.5)
+        mode=mode, window=window, sm_scale=HEAD_DIM ** -0.5)
     bh, zb = BATCH * N_KV * GROUPS, BATCH * N_KV
     _compile_has_kernel(
         lambda q, k, v, regs, kvlen: ops.int8_attention_fp(
@@ -120,3 +119,14 @@ def test_int8_attention_compiles(chip, block):
         chip((bh, SEQ, HEAD_DIM), "uint8"), chip((zb, SEQ, HEAD_DIM), "int8"),
         chip((zb, SEQ, HEAD_DIM), "int8"), chip((1, 8), "float32"),
         chip((1, 1), "int32"))
+
+
+@pytest.mark.parametrize("block", [None, *tuning.ATTN_CANDIDATES])
+def test_int8_attention_compiles(chip, block):
+    _compile_attention(chip, block, "sliding", WINDOW)
+
+
+# The skip predicate and the clamped kv index maps in the causal mode.
+@pytest.mark.parametrize("block", [None, *tuning.ATTN_CANDIDATES])
+def test_int8_attention_causal_compiles(chip, block):
+    _compile_attention(chip, block, "causal", 0)
