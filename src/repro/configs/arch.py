@@ -69,6 +69,7 @@ class ArchConfig:
 
     mlp_kind: str = "gelu"         # gelu|relu|sq_relu|swiglu|geglu|reglu
     norm_kind: str = "rmsnorm"
+    norm_eps: Optional[float] = None   # None: the norm's own default
     use_bias: bool = False
     rope_theta: Optional[float] = 10000.0
     tie_embeddings: bool = False
@@ -76,6 +77,13 @@ class ArchConfig:
     sliding_window: Optional[int] = None
 
     pattern: tuple = ("attn",)
+    first_k_dense: int = 0         # dense "attn" layers before the pattern
+    # latent attention (MLA); kv_lora_rank 0 = the head-split projections.
+    # head_dim is then the query-key head dim (nope + rope).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # hybrid
     local_window: Optional[int] = None
     lru_width: Optional[int] = None
@@ -157,7 +165,7 @@ def names() -> list:
 
 def _ensure_loaded():
     if not _REGISTRY:
-        from . import (command_r_35b, moonshot_v1_16b_a3b,      # noqa: F401
+        from . import (command_r_35b, moonlight_16b_a3b,        # noqa: F401
                        nemotron_4_340b, paligemma_3b,
                        qwen2_moe_a2_7b, recurrentgemma_9b, rwkv6_7b,
                        seamless_m4t_medium, starcoder2_3b, starcoder2_7b)

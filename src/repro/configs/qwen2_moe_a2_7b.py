@@ -27,8 +27,7 @@ CONFIG = ArchConfig(
     rope_theta=1000000.0,
     pattern=("moe",),
     moe=MoeSpec(n_experts=60, top_k=4, d_expert=1408, n_shared=1,
-                d_shared=5632, capacity_factor=2.0, group_size=512,
-                mlp_kind="swiglu"),
+                d_shared=5632, mlp_kind="swiglu"),
     grad_accum=(("train_4k", 2),),
 )
 
@@ -38,8 +37,7 @@ def reduced() -> ArchConfig:
         CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv=4, head_dim=16,
         d_ff=64, vocab=512, loss_chunk=16, q_chunk=16, kv_chunk=16,
         moe=MoeSpec(n_experts=8, top_k=2, d_expert=64, n_shared=1,
-                    d_shared=128, capacity_factor=2.0, group_size=32,
-                    mlp_kind="swiglu"),
+                    d_shared=128, mlp_kind="swiglu"),
         grad_accum=(("train_4k", 1),))
 
 

@@ -677,8 +677,8 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
                kv_len=None, scale: float, step: jax.Array):
     """Backend-dispatched quantized attention core.
 
-    ``q [B, S, KV, G, hd]`` x ``k/v [B, Skv, KV, hd]`` -> ``out [B, S,
-    KV, G, hd]`` through int8 QK^T / online fp32 softmax / int8 PV with
+    ``q [B, S, KV, G, hd]`` x ``k [B, Skv, KV, hd]``, ``v [B, Skv, KV,
+    hdv]`` -> ``out [B, S, KV, G, hdv]`` through int8 QK^T / online fp32 softmax / int8 PV with
     in-hindsight ranges for all four tensors (q, k, v, probabilities).
     ``sites`` is the ``{"q"/"k"/"v"/"p": {"act": leaf}}`` core-site tree
     (see ``models.attention.init_attention_sites``); returns ``(out,
@@ -689,7 +689,7 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
     backends replay — tile choice changes speed, never results.
     """
     b, s, kvh, g, hd = q.shape
-    skv = k.shape[1]
+    skv, hdv = k.shape[1], v.shape[-1]
     cfg = policy.act_estimator
     with jax.named_scope(f"qattn_int8_{policy.backend}"):
         qh, q_st, q_qt = site_quantize(policy, q, sites["q"]["act"], step,
@@ -724,7 +724,7 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
         sched = mod.make_schedule(
             sq=s, skv=skv, hd=hd, bq=bq, bkv=bkv, groups=g, mode=mode,
             window=int(window or 0), prefix_len=int(prefix_len or 0),
-            sm_scale=float(scale))
+            sm_scale=float(scale), hdv=hdv)
 
         # Head-major flatten (exact: transposes/reshapes move values, not
         # bits): q -> [B*KV*G, S, hd], k/v -> [B*KV, Skv, hd].  The outer
@@ -735,7 +735,8 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
                 b * kvh * g, s, hd)
 
         def kvflat(t):
-            return jnp.transpose(t, (0, 2, 1, 3)).reshape(b * kvh, skv, hd)
+            return jnp.transpose(t, (0, 2, 1, 3)).reshape(
+                b * kvh, skv, t.shape[-1])
 
         fused = policy.backend == FUSED
         qat = _QATTN_CACHE.get_or_build(
@@ -743,10 +744,92 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
         out3, stats6 = qat(qflat(qh), kvflat(kh), kvflat(vh),
                            qflat(q_qt.q), kvflat(k_qt.q), kvflat(v_qt.q),
                            jax.lax.stop_gradient(regs), kvl)
-        out = jnp.transpose(out3.reshape(b, kvh, g, s, hd),
+        out = jnp.transpose(out3.reshape(b, kvh, g, s, hdv),
                             (0, 3, 1, 2, 4)).astype(q.dtype)
         p_st = _pstats_vector(policy, stats6, p_lo, p_hi)
         sg = jax.lax.stop_gradient
         stats = {"q": {"act": sg(q_st)}, "k": {"act": sg(k_st)},
                  "v": {"act": sg(v_st)}, "p": {"act": sg(p_st)}}
         return out, stats
+
+
+# ---------------------------------------------------------------------------
+# The grouped contraction of an expert layer: the rows routed to each held
+# expert, sorted by expert, times that expert's weights (see
+# ``kernels/int8_grouped_matmul.py`` for the row layout and its tables).
+# ---------------------------------------------------------------------------
+_QGMM_CACHE = LruCache()
+
+_TGMM_DIMS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _gmm_mod():
+    from repro.kernels import int8_grouped_matmul
+    return int8_grouped_matmul
+
+
+def _make_qgmm(fused: bool):
+    """One custom_vjp per backend.  Forward: the ``alpha * int32`` grouped
+    contraction, on the Pallas kernel (fused) or its jnp reference
+    (simulated), bit-equal.  Backward, shared: fp32 ragged contractions of
+    the on-grid operands, ``dX = g W_e^T`` and ``dW_e = X_e^T g`` over each
+    group's rows (padding and dead rows carry no cotangent)."""
+    mod = _gmm_mod()
+
+    def fwd_math(q_x, q_w, x_zp, alpha, tiles):
+        if fused:
+            return _ops().int8_gmm_fp(q_x, q_w, x_zp, alpha, tiles)
+        return mod.grouped_matmul_reference(q_x, q_w, x_zp, alpha, tiles)
+
+    @jax.custom_vjp
+    def qg(xq, wq, q_x, q_w, x_zp, alpha, tiles):
+        return fwd_math(q_x, q_w, x_zp, alpha, tiles)
+
+    def fwd(xq, wq, q_x, q_w, x_zp, alpha, tiles):
+        return fwd_math(q_x, q_w, x_zp, alpha, tiles), (xq, wq, q_x, q_w,
+                                                         tiles)
+
+    def bwd(res, g):
+        xq, wq, q_x, q_w, tiles = res
+        gf = jnp.where(mod.row_valid(tiles)[:, None], g.astype(jnp.float32),
+                       0.0)
+        dx = jax.lax.ragged_dot(
+            gf, jnp.swapaxes(wq.astype(jnp.float32), 1, 2), tiles.group_rows,
+            preferred_element_type=jnp.float32)
+        dw = jax.lax.ragged_dot_general(
+            xq.astype(jnp.float32), gf, tiles.group_rows, _TGMM_DIMS,
+            preferred_element_type=jnp.float32)
+        z = jnp.zeros((), jnp.float32)
+        return (dx.astype(xq.dtype), dw.astype(wq.dtype), float0_like(q_x),
+                float0_like(q_w), z, z,
+                jax.tree_util.tree_map(float0_like, tiles))
+
+    qg.defvjp(fwd, bwd)
+    return qg
+
+
+def qgmm(policy, xq: jax.Array, xqt: Optional[QTensor], wq: jax.Array,
+         wqt: Optional[QTensor], tiles, out_dtype=None) -> jax.Array:
+    """Quantized-site grouped contraction: row ``r`` of ``xq [R, K]`` times
+    ``wq[group(r)]`` of ``wq [G, K, N]``, rows laid out as ``tiles``
+    (``kernels.int8_grouped_matmul.GmmTiles``) say.
+
+    With int8 images for both operands it runs integer-exact on either
+    backend (the fused backend on the grouped MXU kernel); otherwise it is
+    the fp ragged contraction of the on-grid tensors.
+    """
+    out_dtype = out_dtype or xq.dtype
+    if xqt is None or wqt is None or not int8_matmul_eligible(policy):
+        with jax.named_scope("qgmm_fp"):
+            y = jax.lax.ragged_dot(xq, wq, tiles.group_rows,
+                                   preferred_element_type=jnp.float32)
+            valid = _gmm_mod().row_valid(tiles)[:, None]
+            return jnp.where(valid, y, 0.0).astype(out_dtype)
+    fused = policy.backend == FUSED
+    qg = _QGMM_CACHE.get_or_build(fused, lambda: _make_qgmm(fused))
+    alpha = (xqt.scale * wqt.scale).astype(jnp.float32)
+    with jax.named_scope(f"qgmm_int8_{policy.backend}"):
+        y = qg(xq, wq, xqt.q, wqt.q, xqt.zero_point, alpha, tiles)
+    return y.astype(out_dtype)

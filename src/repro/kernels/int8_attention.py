@@ -31,8 +31,9 @@ statistics partials.  ``reduce_pstats`` is the single shared reduction of
 the per-(head, q block) partials for BOTH backends.
 
 Layout: q is uint8 ``[BH, sq, hd]`` with ``BH = B * KV * G`` (GQA
-head-major flattening); k/v are int8 ``[ZB, skv, hd]`` with ``ZB = B *
-KV`` — the kernel broadcasts each kv head over its G query heads through
+head-major flattening); k is int8 ``[ZB, skv, hd]`` and v int8 ``[ZB,
+skv, hdv]`` with ``ZB = B * KV`` (the value head dim ``hdv`` may differ
+from the query-key one, as in latent attention) — the kernel broadcasts each kv head over its G query heads through
 the BlockSpec index map (``bh // G``), so GQA never materializes repeated
 k/v.
 
@@ -72,7 +73,8 @@ class AttnSchedule:
 
     sq: int                # query length
     skv: int               # key/value length
-    hd: int                # head dim
+    hd: int                # query-key head dim
+    hdv: int               # value head dim (the output's)
     bq: int                # q block rows
     bkv: int               # kv block cols
     groups: int            # G = n_heads // n_kv (GQA broadcast factor)
@@ -102,14 +104,16 @@ class AttnSchedule:
 
 def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
                   groups: int, mode: str, window: int = 0,
-                  prefix_len: int = 0, sm_scale: float) -> AttnSchedule:
+                  prefix_len: int = 0, sm_scale: float,
+                  hdv: int = 0) -> AttnSchedule:
     """Resolve block sizes and the per-q-block kv visitation width.
 
     For every mode but ``sliding`` each q block walks all kv blocks.
     For ``sliding`` the width is the *block-local fast path*: the maximum
     number of kv blocks any q block's window can touch — O(S * w) grid
     steps instead of O(S^2).  In every mode the block-level ``visited``
-    predicate then skips the walk's fully-masked blocks.
+    predicate then skips the walk's fully-masked blocks.  ``hdv`` is the
+    value head dim (0: the query-key ``hd``).
     """
     if mode not in MASK_MODES:
         raise ValueError(f"unknown mask mode {mode!r}; expected {MASK_MODES}")
@@ -120,8 +124,10 @@ def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
     # int32 exactness headroom: |rp| <= 255, |v| <= 128 -> the PV int32
     # accumulator stays below 2^24 (exact through the fp32 cast) for
     # bkv <= 512; same bound for the QK^T accumulator over hd.
-    if hd > 512 or bkv > 512:
-        raise ValueError(f"head_dim/bkv must be <= 512 (got {hd}, {bkv})")
+    hdv = hdv or hd
+    if max(hd, hdv, bkv) > 512:
+        raise ValueError(f"head dims/bkv must be <= 512 (got {hd}, {hdv}, "
+                         f"{bkv})")
     nq = -(-sq // bq)
     nkv = -(-skv // bkv)
     if mode == "sliding":
@@ -133,7 +139,8 @@ def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
         width = min(width, nkv)
     else:
         width = nkv
-    return AttnSchedule(sq=sq, skv=skv, hd=hd, bq=bq, bkv=bkv, groups=groups,
+    return AttnSchedule(sq=sq, skv=skv, hd=hd, hdv=hdv, bq=bq, bkv=bkv,
+                        groups=groups,
                         mode=mode, window=int(window), prefix_len=int(prefix_len),
                         sm_scale=float(sm_scale), width=width)
 
@@ -365,7 +372,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
     def _init():
         m_sc[...] = jnp.full((S.bq, 1), NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros((S.bq, 1), jnp.float32)
-        acc_sc[...] = jnp.zeros((S.bq, S.hd), jnp.float32)
+        acc_sc[...] = jnp.zeros((S.bq, S.hdv), jnp.float32)
         st_sc[...] = stats_tile(*_stats_init_values())
 
     ki = _kv_block_base(i, S) + t
@@ -397,10 +404,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
             acc_qk, mask, m_sc[...], alpha_qk, scale_p, zp_p)
         zp_p_i = zp_p.astype(jnp.int32)
         p_s8 = (rp + (zp_p_i - 128)).astype(jnp.int8)
-        vsum = jnp.sum(v.astype(jnp.int32), axis=0, keepdims=True)  # [1, hd]
+        vsum = jnp.sum(v.astype(jnp.int32), axis=0, keepdims=True)  # [1, hdv]
         acc_pv = jax.lax.dot_general(
             p_s8, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)                      # [bq, hd]
+            preferred_element_type=jnp.int32)                      # [bq, hdv]
         acc_pv = acc_pv + (128 - zp_p_i) * vsum
         acc, l = _accumulate(acc_sc[...], l_sc[...], corr, acc_pv, rp,
                              alpha_pv, scale_p)
@@ -432,9 +439,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
 
 
 def attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, *, sched: AttnSchedule):
-    """Raw pallas_call.  ``q_u8`` uint8 [BH, sq, hd]; ``k_i8``/``v_i8``
-    int8 [ZB, skv, hd] (ZB = BH // groups); ``regs`` fp32 [1, 8]; ``kvlen``
-    int32 [1, 1].  Returns ``(out [BH, sq, hd] f32, ml [BH, sq, 2] f32,
+    """Raw pallas_call.  ``q_u8`` uint8 [BH, sq, hd]; ``k_i8`` int8 [ZB,
+    skv, hd] and ``v_i8`` int8 [ZB, skv, hdv] (ZB = BH // groups); ``regs``
+    fp32 [1, 8]; ``kvlen`` int32 [1, 1].  Returns ``(out [BH, sq, hdv]
+    f32, ml [BH, sq, 2] f32,
     pstats [BH, nq, 6] f32)``."""
     S = sched
     bh = q_u8.shape[0]
@@ -454,25 +462,25 @@ def attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, *, sched: AttnSchedule):
         in_specs=[
             pl.BlockSpec((1, S.bq, S.hd), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, S.bkv, S.hd), kvmap),
-            pl.BlockSpec((1, S.bkv, S.hd), kvmap),
+            pl.BlockSpec((1, S.bkv, S.hdv), kvmap),
             pl.BlockSpec((1, 1, S.bkv), ksum_map),
             pl.BlockSpec((1, 8), lambda b, i, t: (0, 0)),
             pl.BlockSpec((1, 1), lambda b, i, t: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, S.bq, S.hd), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, S.bq, S.hdv), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, S.bq, 2), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, 1) + STATS_TILE, lambda b, i, t: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, S.sq, S.hd), jnp.float32),
+            jax.ShapeDtypeStruct((bh, S.sq, S.hdv), jnp.float32),
             jax.ShapeDtypeStruct((bh, S.sq, 2), jnp.float32),
             jax.ShapeDtypeStruct((bh, S.nq) + STATS_TILE, jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((S.bq, 1), jnp.float32),
             pltpu.VMEM((S.bq, 1), jnp.float32),
-            pltpu.VMEM((S.bq, S.hd), jnp.float32),
+            pltpu.VMEM((S.bq, S.hdv), jnp.float32),
             pltpu.VMEM(STATS_TILE, jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -513,7 +521,7 @@ def attention_core_reference(q_u8, k_i8, v_i8, regs, kvlen, *,
     qz = _pad_axis(q_u8, S.nq * S.bq, 1).reshape(
         zb, S.groups, S.nq, S.bq, S.hd)
     kz = _pad_axis(k_i8, S.nkv * S.bkv, 1).reshape(zb, S.nkv, S.bkv, S.hd)
-    vz = _pad_axis(v_i8, S.nkv * S.bkv, 1).reshape(zb, S.nkv, S.bkv, S.hd)
+    vz = _pad_axis(v_i8, S.nkv * S.bkv, 1).reshape(zb, S.nkv, S.bkv, S.hdv)
     zp_q, alpha_qk, scale_p, zp_p, alpha_pv, p_lo, p_hi = (
         regs[0, 0], regs[0, 1], regs[0, 2], regs[0, 3], regs[0, 4],
         regs[0, 5], regs[0, 6])
@@ -559,7 +567,7 @@ def attention_core_reference(q_u8, k_i8, v_i8, regs, kvlen, *,
 
         m0 = jnp.full((zb, S.groups, S.bq, 1), NEG_INF, jnp.float32)
         l0 = jnp.zeros((zb, S.groups, S.bq, 1), jnp.float32)
-        a0 = jnp.zeros((zb, S.groups, S.bq, S.hd), jnp.float32)
+        a0 = jnp.zeros((zb, S.groups, S.bq, S.hdv), jnp.float32)
         st0 = _stats_init((zb, S.groups))
         (m, l, acc, st), _ = jax.lax.scan(kv_body, (m0, l0, a0, st0),
                                           jnp.arange(S.width))
@@ -570,7 +578,7 @@ def attention_core_reference(q_u8, k_i8, v_i8, regs, kvlen, *,
     outs, mls, sts = jax.lax.map(q_body, jnp.arange(S.nq))
     # [nq, ZB, G, bq, ...] -> kernel element order [BH, sq, ...]
     out = jnp.transpose(outs, (1, 2, 0, 3, 4)).reshape(
-        bh, S.nq * S.bq, S.hd)[:, :S.sq]
+        bh, S.nq * S.bq, S.hdv)[:, :S.sq]
     ml = jnp.transpose(mls, (1, 2, 0, 3, 4)).reshape(
         bh, S.nq * S.bq, 2)[:, :S.sq]
     pstats = jnp.transpose(sts, (1, 2, 0, 3)).reshape(bh, S.nq, STAT_SLOTS)
@@ -591,7 +599,7 @@ def attention_core_reference(q_u8, k_i8, v_i8, regs, kvlen, *,
 # ---------------------------------------------------------------------------
 def attention_core_backward(qh, kh, vh, q_u8, k_i8, v_i8, regs, kvlen,
                             out, ml, g_out, *, sched: AttnSchedule):
-    """Returns ``(dq [BH, sq, hd], dk [ZB, skv, hd], dv [ZB, skv, hd])``
+    """Returns ``(dq [BH, sq, hd], dk [ZB, skv, hd], dv [ZB, skv, hdv])``
     fp32 cotangents w.r.t. the on-grid (dequantized) q/k/v tensors."""
     S = sched
     bh = q_u8.shape[0]
@@ -608,19 +616,19 @@ def attention_core_backward(qh, kh, vh, q_u8, k_i8, v_i8, regs, kvlen,
     d_row = jnp.einsum("bsh,bsh->bs", gf, out.astype(jnp.float32))
     qz = qsplit(q_u8, S.hd)
     qhz = qsplit(qh.astype(jnp.float32), S.hd)
-    gz = qsplit(gf, S.hd)
+    gz = qsplit(gf, S.hdv)
     mz = qsplit(ml[..., 0:1], 1)[..., 0]                   # [ZB,G,nq,bq]
     lz = qsplit(ml[..., 1:2], 1)[..., 0]
     dz = qsplit(d_row[..., None], 1)[..., 0]
     kz = ksplit(k_i8, S.hd)
     khz = ksplit(kh.astype(jnp.float32), S.hd)
-    vhz = ksplit(vh.astype(jnp.float32), S.hd)
+    vhz = ksplit(vh.astype(jnp.float32), S.hdv)
     zp_q, alpha_qk = regs[0, 0], regs[0, 1]
     kvl = kvlen[0, 0]
     sm = jnp.float32(S.sm_scale)
 
     def outer(carry, i):
-        dk_acc, dv_acc = carry                              # [ZB, nkv, bkv, hd]
+        dk_acc, dv_acc = carry                     # [ZB, nkv, bkv, hd / hdv]
         rq = (jax.lax.dynamic_index_in_dim(qz, i, 2, False).astype(jnp.int32)
               - zp_q.astype(jnp.int32))
         qh_i = jax.lax.dynamic_index_in_dim(qhz, i, 2, False)
@@ -663,11 +671,11 @@ def attention_core_backward(qh, kh, vh, q_u8, k_i8, v_i8, regs, kvlen,
         return (dk_acc, dv_acc), dq_i
 
     dk0 = jnp.zeros((zb, S.nkv, S.bkv, S.hd), jnp.float32)
-    dv0 = jnp.zeros((zb, S.nkv, S.bkv, S.hd), jnp.float32)
+    dv0 = jnp.zeros((zb, S.nkv, S.bkv, S.hdv), jnp.float32)
     (dk_acc, dv_acc), dqs = jax.lax.scan(outer, (dk0, dv0),
                                          jnp.arange(S.nq))
     dq = jnp.transpose(dqs, (1, 2, 0, 3, 4)).reshape(
         bh, sqp, S.hd)[:, :S.sq]
     dk = dk_acc.reshape(zb, skp, S.hd)[:, :S.skv]
-    dv = dv_acc.reshape(zb, skp, S.hd)[:, :S.skv]
+    dv = dv_acc.reshape(zb, skp, S.hdv)[:, :S.skv]
     return dq, dk, dv

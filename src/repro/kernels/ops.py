@@ -25,6 +25,7 @@ from repro.core.quant import QuantSpec, scale_zero_point
 from . import stats_values, tuning
 from .fused_quantize import DEFAULT_BLOCK, fused_quantize_kernel
 from .int8_attention import AttnSchedule, attention_kernel
+from .int8_grouped_matmul import GmmTiles, grouped_matmul
 from .int8_matmul import int8_matmul_fp_kernel, int8_matmul_fused_kernel
 from .stochastic_quantize import stochastic_quantize_kernel
 
@@ -558,7 +559,7 @@ def _int8_conv_fp_jit(
 def int8_attention_fp(
     q_u8: jax.Array,         # uint8 [BH, sq, hd], asymmetric grid
     k_i8: jax.Array,         # int8  [ZB, skv, hd], symmetric
-    v_i8: jax.Array,         # int8  [ZB, skv, hd], symmetric
+    v_i8: jax.Array,         # int8  [ZB, skv, hdv], symmetric
     regs: jax.Array,         # fp32 [1, 8] quant registers (see int8_attention)
     kvlen: jax.Array,        # int32 [1, 1] runtime kv length bound
     *,
@@ -566,7 +567,7 @@ def int8_attention_fp(
 ):
     """Fused flash-style int8 attention core with in-kernel p-site stats.
 
-    Returns ``(out fp32 [BH, sq, hd], ml fp32 [BH, sq, 2] final softmax
+    Returns ``(out fp32 [BH, sq, hdv], ml fp32 [BH, sq, 2] final softmax
     (max, denom) residuals, pstats fp32 [BH, nq, 8, 128] per-(head, q
     block) probability statistics tiles)``.  The block plan is baked into
     ``sched`` at dispatch (resolved via :mod:`repro.kernels.tuning`), so
@@ -574,6 +575,21 @@ def int8_attention_fp(
     """
     with jax.named_scope("k_attn_fwd"):
         return attention_kernel(q_u8, k_i8, v_i8, regs, kvlen, sched=sched)
+
+
+@jax.jit
+def int8_gmm_fp(
+    x_q: jax.Array,          # uint8 [R, K], asymmetric grid, rows by group
+    w_q: jax.Array,          # int8  [G, K, N], symmetric
+    x_zp: jax.Array,
+    alpha: jax.Array,        # s_x * s_w
+    tiles: GmmTiles,
+):
+    """Grouped int8 contraction of an expert layer on the MXU kernel:
+    ``alpha * (x_q - zp) @ w_q[group(row)]`` per row, fp32 ``[R, N]``,
+    padding and dead rows zero (``int8_grouped_matmul``)."""
+    with jax.named_scope("k_int8_gmm"):
+        return grouped_matmul(x_q, w_q, x_zp, alpha, tiles)
 
 
 def int8_matmul_fused(

@@ -67,6 +67,11 @@ def run(args):
 
     cfg = configs.get_reduced(args.arch) if args.reduced \
         else configs.get(args.arch)
+    if cfg.kv_lora_rank:
+        raise SystemExit(
+            f"{cfg.name}: serving latent attention is not supported yet (it "
+            f"needs a latent KV cache and absorbed decode projections); this "
+            f"config trains only")
     if args.int8_cache:
         cfg = dataclasses.replace(cfg, cache_dtype="int8")
     policy = QuantPolicy.disabled() if args.policy == "fp32" \

@@ -41,6 +41,7 @@ from repro import checkpoint, configs, data, telemetry
 from repro.core.estimators import ALL_ESTIMATORS
 from repro.core.policy import QuantPolicy
 from repro.launch import compile_cache
+from repro.models import moe
 from repro.optim import adamw, sgdm
 from repro.optim.schedules import cosine
 from repro.runtime import steps as steps_mod
@@ -284,9 +285,12 @@ def run(args):
                                    "phases_ms": phases}) + "\n")
             logf.flush()
         if records is not None:
-            tele_sink.write(step, records, events,
-                            perf=timer.perf_record(items=tokens_per_step,
-                                                   unit="tokens"))
+            perf = timer.perf_record(items=tokens_per_step, unit="tokens")
+            # An expert layer's routing counters (models/moe.py COUNTERS).
+            routed = {k: met[k] for k in moe.COUNTERS if k in met}
+            if routed:
+                perf["moe"] = routed
+            tele_sink.write(step, records, events, perf=perf)
         if stop["now"]:
             print("[train] preemption signal received — exiting cleanly")
             break

@@ -36,7 +36,7 @@ from repro.core.policy import QuantPolicy
 from repro.core.state import init_range_state, make_range_state
 from repro.runtime.sharding import attn_hints
 
-from .layers import apply_rope
+from .layers import apply_norm, apply_rope
 
 NEG_INF = -1e30  # large-but-finite: keeps fully-masked rows NaN-free
 
@@ -118,7 +118,7 @@ def _mask_block(q_pos, kv_pos, mode: str, window: Optional[int],
 def _chunked_attn(q, k, v, *, mode: str, window, prefix_len, kv_len,
                   q_start: int, q_chunk: int, kv_chunk: int, scale: float):
     b, sq, nkv, g, hd = q.shape
-    skv = k.shape[1]
+    skv, hdv = k.shape[1], v.shape[-1]
     qc = min(q_chunk, sq)
     kc = min(kv_chunk, skv)
     # configs pick chunk sizes that divide the shape; assert to fail loudly.
@@ -127,7 +127,7 @@ def _chunked_attn(q, k, v, *, mode: str, window, prefix_len, kv_len,
 
     qb = q.reshape(b, nq, qc, nkv, g, hd)
     kb = k.reshape(b, nk, kc, nkv, hd)
-    vb = v.reshape(b, nk, kc, nkv, hd)
+    vb = v.reshape(b, nk, kc, nkv, hdv)
 
     def q_body(qi):
         qblk = qb[:, qi].astype(jnp.float32) * scale   # [B, qc, KV, G, hd]
@@ -151,13 +151,13 @@ def _chunked_attn(q, k, v, *, mode: str, window, prefix_len, kv_len,
 
         m0 = jnp.full((b, nkv, g, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, nkv, g, qc), jnp.float32)
-        a0 = jnp.zeros((b, nkv, g, qc, hd), jnp.float32)
+        a0 = jnp.zeros((b, nkv, g, qc, hdv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0), jnp.arange(nk))
         out = acc / jnp.maximum(l, 1e-30)[..., None]          # [B, KV, G, qc, hd]
         return jnp.transpose(out, (0, 3, 1, 2, 4))            # [B, qc, KV, G, hd]
 
     out = jax.lax.map(q_body, jnp.arange(nq))                  # [nq, B, qc, ...]
-    out = jnp.transpose(out, (1, 0, 2, 3, 4, 5)).reshape(b, sq, nkv, g, hd)
+    out = jnp.transpose(out, (1, 0, 2, 3, 4, 5)).reshape(b, sq, nkv, g, hdv)
     return out.astype(q.dtype)
 
 
@@ -488,3 +488,104 @@ def attention_layer(
     if "bo" in params:
         y = y + params["bo"].astype(y.dtype)
     return y, new_sites, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3, Moonlight), training and
+# prefill.  k and v come up from a ``kv_lora_rank``-wide latent (RMSNorm'd
+# in fp) through one projection; a ``rope``-wide RoPE key shared by every
+# head is concatenated after each head's ``k_nope``.  Every projection is a
+# quantized site; the core is the same ``backend.qattention`` site, with a
+# query-key head dim (nope + rope) above the value head dim.
+# ---------------------------------------------------------------------------
+def init_mla(key, d_model: int, n_heads: int, nope: int, rope: int,
+             v_dim: int, rank: int, dtype=jnp.float32) -> dict:
+    """``q_proj [D, H, nope+rope]``, ``kv_a [D, rank+rope]``, ``kv_b
+    [rank, H, nope+v]``, ``o_proj [H, v, D]`` and the latent's norm."""
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    s = d_model ** -0.5
+    return {
+        "q_proj": (jax.random.normal(kq, (d_model, n_heads, nope + rope))
+                   * s).astype(dtype),
+        "kv_a": (jax.random.normal(ka, (d_model, rank + rope)) * s
+                 ).astype(dtype),
+        "kv_norm": {"scale": jnp.ones((rank,), jnp.float32)},
+        "kv_b": (jax.random.normal(kb, (rank, n_heads, nope + v_dim))
+                 * rank ** -0.5).astype(dtype),
+        "o_proj": (jax.random.normal(ko, (n_heads, v_dim, d_model))
+                   * (n_heads * v_dim) ** -0.5).astype(dtype),
+    }
+
+
+def init_mla_sites() -> dict:
+    sites = {name: qlinear.init_site() for name in ("q", "kv_a", "kv_b", "o")}
+    sites["core"] = init_attention_sites()["core"]
+    return sites
+
+
+def rope_halves(x: jax.Array) -> jax.Array:
+    """Reorder the last dim from interleaved pairs to halves, as the
+    published DeepSeek-V3 rotary does before rotating (``x[..., 0::2]``
+    then ``x[..., 1::2]``)."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def mla_layer(params: dict, sites: dict, x: jax.Array, *, n_heads: int,
+              nope: int, rope: int, v_dim: int, rank: int, rope_theta: float,
+              norm_eps: Optional[float], positions: Optional[jax.Array] = None,
+              policy: QuantPolicy, seed: jax.Array, step: jax.Array,
+              q_chunk: int = 2048, kv_chunk: int = 1024,
+              dense_attn_max: int = 4096) -> tuple[jax.Array, dict]:
+    """Causal latent attention over ``x [B, S, D]``; returns ``(y,
+    new_sites)``."""
+    b, s, _ = x.shape
+    new_sites = {}
+    # One activation site for the two projections of x (its range state
+    # lives on the "q" site).
+    xq, in_stats, xqi = qlinear.act_quant_site(x, sites["q"]["act"], policy,
+                                               step)
+    q, sq = qlinear.qdense_pre(xq, params["q_proj"], sites["q"], policy,
+                               einsum_spec="bsd,dhe->bshe", seed=seed,
+                               step=step, qinfo=xqi)
+    sq["act"] = in_stats
+    new_sites["q"] = sq
+    with jax.named_scope("mla_latent"):
+        ckv, new_sites["kv_a"] = qlinear.qdense_pre(
+            xq, params["kv_a"], sites["kv_a"], policy,
+            einsum_spec="bsd,dr->bsr", seed=seed + 1, step=step, qinfo=xqi)
+        latent = apply_norm(ckv[..., :rank], params["kv_norm"], "rmsnorm",
+                            norm_eps)
+        kv, new_sites["kv_b"] = qlinear.qeinsum(
+            "bsr,rhe->bshe", latent, params["kv_b"], sites["kv_b"], policy,
+            seed=seed + 2, step=step)
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    q_pe = apply_rope(rope_halves(q[..., nope:]), positions, rope_theta)
+    k_pe = apply_rope(rope_halves(ckv[..., None, rank:]), positions,
+                      rope_theta)                               # [B,S,1,rope]
+    qh = jnp.concatenate([q[..., :nope], q_pe], axis=-1)[:, :, :, None]
+    kh = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, (b, s, n_heads, rope))], -1)
+    v = kv[..., nope:]
+    scale = (nope + rope) ** -0.5
+
+    core_stats = None
+    if backend.qattention_eligible(policy):
+        out, core_stats = backend.qattention(
+            policy, qh, kh, v, sites["core"], mode="causal", scale=scale,
+            step=step)
+    elif s <= dense_attn_max:
+        out = _dense_attn(qh, kh, v, mode="causal", window=None,
+                          prefix_len=None, kv_len=None, scale=scale)
+    else:
+        out = _chunked_attn(qh, kh, v, mode="causal", window=None,
+                            prefix_len=None, kv_len=None, q_start=0,
+                            q_chunk=q_chunk, kv_chunk=kv_chunk, scale=scale)
+    if core_stats is None:
+        core_stats = jax.tree_util.tree_map(
+            lambda _: qlinear.stats_zeros(policy), sites["core"])
+    new_sites["core"] = core_stats
+    y, new_sites["o"] = qlinear.qeinsum("bshe,hed->bsd", out[:, :, :, 0],
+                                        params["o_proj"], sites["o"], policy,
+                                        seed=seed + 3, step=step)
+    return y, new_sites

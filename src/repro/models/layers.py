@@ -37,10 +37,13 @@ def layernorm(x: jax.Array, weight: jax.Array, bias: Optional[jax.Array],
     return y.astype(x.dtype)
 
 
-def apply_norm(x: jax.Array, params: dict, kind: str) -> jax.Array:
+def apply_norm(x: jax.Array, params: dict, kind: str,
+               eps: Optional[float] = None) -> jax.Array:
+    """``eps`` None: the norm's own default."""
+    kw = {} if eps is None else {"eps": eps}
     if kind == "rmsnorm":
-        return rmsnorm(x, params["scale"])
-    return layernorm(x, params["scale"], params.get("bias"))
+        return rmsnorm(x, params["scale"], **kw)
+    return layernorm(x, params["scale"], params.get("bias"), **kw)
 
 
 def init_norm(d: int, kind: str, use_bias: bool) -> dict:

@@ -95,7 +95,7 @@ def _embed_tokens(params, tokens, cfg, policy):
 def _trunk(params, sites, batch, cfg, policy, seed, step, caches=None):
     """Returns (hidden [B,S,D], new_sites, new_caches, metrics)."""
     new_sites: dict = {}
-    metrics = {"aux_loss": jnp.float32(0.0), "z_loss": jnp.float32(0.0)}
+    metrics = transformer.zero_metrics(cfg)
     enc_out = enc_len = None
     prefix_len = None
 
@@ -109,7 +109,8 @@ def _trunk(params, sites, batch, cfg, policy, seed, step, caches=None):
             params["encoder"], sites["encoder"], ex, cfg=cfg,
             pattern=cfg.enc_pattern, policy=policy,
             seed=seed + 2_000_000, step=step, positions=epos)
-        enc_out = layers.apply_norm(enc_out, params["enc_norm"], cfg.norm_kind)
+        enc_out = layers.apply_norm(enc_out, params["enc_norm"], cfg.norm_kind,
+                                   cfg.norm_eps)
         new_sites["encoder"] = enc_sites
         metrics = {k: metrics[k] + emet[k] for k in metrics}
         enc_len = batch.get("frame_len")
@@ -137,7 +138,8 @@ def _trunk(params, sites, batch, cfg, policy, seed, step, caches=None):
     new_sites["decoder"] = dec_sites
     metrics = {k: metrics[k] + dmet[k] for k in metrics}
 
-    x = layers.apply_norm(x, params["final_norm"], cfg.norm_kind)
+    x = layers.apply_norm(x, params["final_norm"], cfg.norm_kind,
+                          cfg.norm_eps)
     return x, new_sites, new_caches, metrics
 
 

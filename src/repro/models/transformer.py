@@ -3,7 +3,7 @@
 One engine covers all ten architectures via a *pattern* of block kinds:
 
   dense LMs        pattern ("attn",)                 starcoder2, nemotron, command-r
-  MoE LMs          pattern ("moe",)                  qwen2-moe, moonshot
+  MoE LMs          pattern ("moe",)                  qwen2-moe, moonlight
   RWKV-6           pattern ("rwkv",)                 rwkv6-7b
   hybrid           pattern ("rec","rec","local")     recurrentgemma (1:2 RG-LRU:local)
   enc-dec          enc pattern ("enc",), dec ("xattn",)   seamless-m4t
@@ -13,7 +13,10 @@ The stack is compiled as a ``lax.scan`` over pattern *repeats* (MaxText-
 style): the HLO contains one trace of the pattern unit regardless of depth,
 which keeps 96-layer compiles tractable and makes the per-layer quant-range
 states stack into ``[repeats, 3]`` leaves that ride the scan's xs/ys.  A
-ragged tail (e.g. recurrentgemma's 38 = 12x3 + 2) is applied unrolled.
+ragged tail (e.g. recurrentgemma's 38 = 12x3 + 2) is applied unrolled, and
+so are ``cfg.first_k_dense`` leading dense layers before the scan (Moonlight:
+one dense layer, then the expert layers).  A config with ``kv_lora_rank``
+runs latent attention (``attention.mla_layer``) in its attention blocks.
 
 Quantization sites mirror the parameter tree one-to-one; activation-site
 updates come back through the scan ys, gradient-site statistics flow
@@ -50,6 +53,21 @@ _SEED_STRIDE = 64
 # ===========================================================================
 # Per-block init / apply.
 # ===========================================================================
+def zero_metrics(cfg) -> dict:
+    """The per-step metrics a stack sums over its layers (all zero)."""
+    names = ("aux_loss", "z_loss") + (moe_mod.COUNTERS if cfg.moe else ())
+    return {k: jnp.float32(0.0) for k in names}
+
+
+def _init_self_attn(key, cfg, dt) -> dict:
+    if cfg.kv_lora_rank:
+        return attn.init_mla(key, cfg.d_model, cfg.n_heads,
+                             cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                             cfg.v_head_dim, cfg.kv_lora_rank, dt)
+    return attn.init_attention(key, cfg.d_model, cfg.n_heads, cfg.n_kv,
+                               cfg.head_dim, cfg.use_bias, dt)
+
+
 def _init_block(key, kind: str, cfg) -> dict:
     dt = jnp.dtype(cfg.param_dtype)
     k1, k2, k3, k4 = jax.random.split(key, 4)
@@ -57,8 +75,7 @@ def _init_block(key, kind: str, cfg) -> dict:
     if kind in ("attn", "local", "enc"):
         return {
             "ln1": norm(),
-            "attn": attn.init_attention(k1, cfg.d_model, cfg.n_heads, cfg.n_kv,
-                                        cfg.head_dim, cfg.use_bias, dt),
+            "attn": _init_self_attn(k1, cfg, dt),
             "ln2": norm(),
             "mlp": layers.init_mlp(k2, cfg.d_model, cfg.d_ff, cfg.mlp_kind,
                                    cfg.use_bias, dt),
@@ -66,8 +83,7 @@ def _init_block(key, kind: str, cfg) -> dict:
     if kind == "moe":
         return {
             "ln1": norm(),
-            "attn": attn.init_attention(k1, cfg.d_model, cfg.n_heads, cfg.n_kv,
-                                        cfg.head_dim, cfg.use_bias, dt),
+            "attn": _init_self_attn(k1, cfg, dt),
             "ln2": norm(),
             "moe": moe_mod.init_moe(k2, cfg.d_model, cfg.moe, dt),
         }
@@ -103,11 +119,13 @@ def _init_block(key, kind: str, cfg) -> dict:
 
 
 def _init_block_sites(kind: str, cfg) -> dict:
+    self_attn = attn.init_mla_sites() if cfg.kv_lora_rank \
+        else attn.init_attention_sites()
     if kind in ("attn", "local", "enc"):
-        return {"attn": attn.init_attention_sites(),
+        return {"attn": self_attn,
                 "mlp": layers.init_mlp_sites(cfg.mlp_kind)}
     if kind == "moe":
-        return {"attn": attn.init_attention_sites(),
+        return {"attn": self_attn,
                 "moe": moe_mod.init_moe_sites(cfg.moe)}
     if kind == "xattn":
         return {"attn": attn.init_attention_sites(),
@@ -125,6 +143,10 @@ def _init_block_sites(kind: str, cfg) -> dict:
 def _init_block_cache(kind: str, cfg, batch: int, cache_len: int) -> dict:
     """Decode-state pytree for one block (zeros; prefill fills it)."""
     cdt = jnp.dtype(cfg.cache_dtype)
+    if cfg.kv_lora_rank:
+        raise NotImplementedError(
+            f"{cfg.name}: latent attention has no decode cache yet (it needs "
+            f"a latent KV cache and absorbed decode projections)")
     if kind in ("attn", "moe", "local", "enc"):
         length = cache_len
         if kind == "local":
@@ -159,7 +181,9 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
     """Returns (x, new_sites, new_cache, metrics)."""
     new_sites: dict = {}
     new_cache: dict = {} if cache is not None else None
-    metrics = {"aux_loss": jnp.float32(0.0), "z_loss": jnp.float32(0.0)}
+    metrics = zero_metrics(cfg)
+    norm = functools.partial(layers.apply_norm, kind=cfg.norm_kind,
+                             eps=cfg.norm_eps)
 
     if kind in ("attn", "moe", "local", "enc", "xattn"):
         # "enc" = bidirectional self-attention (RoPE still applies).
@@ -169,22 +193,37 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
             mode = "sliding"
         if prefix_len is not None and kind in ("attn", "moe"):
             mode = "prefix"
-        h = layers.apply_norm(x, params["ln1"], cfg.norm_kind)
-        a, new_sites["attn"], kv = attn.attention_layer(
-            params["attn"], sites["attn"], h,
-            n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
-            mode=mode, window=window, prefix_len=prefix_len,
-            rope_theta=cfg.rope_theta, positions=positions,
-            cache=None if cache is None else cache["kv"],
-            policy=policy, seed=seed, step=step,
-            q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-            dense_attn_max=cfg.dense_attn_max)
+        h = norm(x, params["ln1"])
+        if cfg.kv_lora_rank:
+            if cache is not None or mode != "causal":
+                raise NotImplementedError(
+                    f"{cfg.name}: latent attention runs causal training "
+                    f"and full-sequence forwards only (no cache)")
+            a, new_sites["attn"] = attn.mla_layer(
+                params["attn"], sites["attn"], h, n_heads=cfg.n_heads,
+                nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
+                v_dim=cfg.v_head_dim, rank=cfg.kv_lora_rank,
+                rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
+                positions=positions, policy=policy, seed=seed, step=step,
+                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                dense_attn_max=cfg.dense_attn_max)
+            kv = None
+        else:
+            a, new_sites["attn"], kv = attn.attention_layer(
+                params["attn"], sites["attn"], h,
+                n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
+                mode=mode, window=window, prefix_len=prefix_len,
+                rope_theta=cfg.rope_theta, positions=positions,
+                cache=None if cache is None else cache["kv"],
+                policy=policy, seed=seed, step=step,
+                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+                dense_attn_max=cfg.dense_attn_max)
         x = x + a
         if cache is not None:
             new_cache["kv"] = kv
 
         if kind == "xattn":
-            h = layers.apply_norm(x, params["lnx"], cfg.norm_kind)
+            h = norm(x, params["lnx"])
             a, new_sites["xattn"], xkv = attn.attention_layer(
                 params["xattn"], sites["xattn"], h,
                 n_heads=cfg.n_heads, n_kv=cfg.n_kv, head_dim=cfg.head_dim,
@@ -197,12 +236,11 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
             if cache is not None:
                 new_cache["xkv"] = xkv
 
-        h = layers.apply_norm(x, params["ln2"], cfg.norm_kind)
+        h = norm(x, params["ln2"])
         if kind == "moe":
-            m, new_sites["moe"], mmet = moe_mod.apply_moe(
+            m, new_sites["moe"], metrics = moe_mod.apply_moe(
                 params["moe"], sites["moe"], h, cfg.moe, policy=policy,
                 seed=seed + 16, step=step)
-            metrics = {k: metrics[k] + mmet[k] for k in metrics}
         else:
             m, new_sites["mlp"] = layers.apply_mlp(
                 params["mlp"], sites["mlp"], h, cfg.mlp_kind, policy,
@@ -211,7 +249,7 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
         return x, new_sites, new_cache, metrics
 
     if kind == "rwkv":
-        h = layers.apply_norm(x, params["ln1"], cfg.norm_kind)
+        h = norm(x, params["ln1"])
         st = None if cache is None else cache["state"]
         xp = None if cache is None else cache["x_time"].astype(h.dtype)
         a, new_sites["time"], (st, x_last) = rwkv6.rwkv_time_mix(
@@ -219,7 +257,7 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
             policy=policy, seed=seed, step=step, chunk=cfg.rwkv_chunk,
             state=st, x_prev=xp)
         x = x + a
-        h = layers.apply_norm(x, params["ln2"], cfg.norm_kind)
+        h = norm(x, params["ln2"])
         xp2 = None if cache is None else cache["x_chan"].astype(h.dtype)
         c, new_sites["chan"], c_last = rwkv6.rwkv_channel_mix(
             params["chan"], sites["chan"], h, policy=policy, seed=seed + 16,
@@ -232,13 +270,13 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
         return x, new_sites, new_cache, metrics
 
     if kind == "rec":
-        h = layers.apply_norm(x, params["ln1"], cfg.norm_kind)
+        h = norm(x, params["ln1"])
         st = None if cache is None else (cache["h"], cache["conv"].astype(h.dtype))
         a, new_sites["rglru"], (hstate, tail) = rglru.apply_rglru(
             params["rglru"], sites["rglru"], h, policy=policy, seed=seed,
             step=step, state=st)
         x = x + a
-        h = layers.apply_norm(x, params["ln2"], cfg.norm_kind)
+        h = norm(x, params["ln2"])
         m, new_sites["mlp"] = layers.apply_mlp(params["mlp"], sites["mlp"], h,
                                                cfg.mlp_kind, policy, seed + 16,
                                                step)
@@ -260,8 +298,14 @@ def _pattern_split(n_layers: int, pattern: tuple) -> tuple[int, tuple]:
     return repeats, tail
 
 
+def _lead(cfg, pattern) -> int:
+    """Leading dense layers of this stack (the decoder's only)."""
+    return cfg.first_k_dense if pattern == cfg.pattern else 0
+
+
 def init_stack(key, cfg, pattern, n_layers: int) -> dict:
-    repeats, tail = _pattern_split(n_layers, pattern)
+    lead = _lead(cfg, pattern)
+    repeats, tail = _pattern_split(n_layers - lead, pattern)
     keys = jax.random.split(key, max(repeats, 1) * len(pattern) + len(tail) + 1)
 
     def unit(r):
@@ -277,22 +321,35 @@ def init_stack(key, cfg, pattern, n_layers: int) -> dict:
                                          *[unit(r) for r in range(repeats)])
     tail_p = {f"t{j}": _init_block(keys[repeats * len(pattern) + j], kind, cfg)
               for j, kind in enumerate(tail)}
-    return {"blocks": stacked, "tail": tail_p}
+    out = {"blocks": stacked, "tail": tail_p}
+    if lead:
+        lead_keys = jax.random.split(keys[-1], lead)
+        out["lead"] = {f"l{j}": _init_block(lead_keys[j], "attn", cfg)
+                       for j in range(lead)}
+    return out
 
 
 def init_stack_sites(cfg, pattern, n_layers: int) -> dict:
-    repeats, tail = _pattern_split(n_layers, pattern)
+    lead = _lead(cfg, pattern)
+    repeats, tail = _pattern_split(n_layers - lead, pattern)
     unit = {f"b{j}": _init_block_sites(kind, cfg)
             for j, kind in enumerate(pattern)}
     stacked = {} if repeats == 0 else jax.tree_util.tree_map(
         lambda x: jnp.broadcast_to(x, (repeats,) + x.shape).copy(), unit)
     tail_s = {f"t{j}": _init_block_sites(kind, cfg)
               for j, kind in enumerate(tail)}
-    return {"blocks": stacked, "tail": tail_s}
+    out = {"blocks": stacked, "tail": tail_s}
+    if lead:
+        out["lead"] = {f"l{j}": _init_block_sites("attn", cfg)
+                       for j in range(lead)}
+    return out
 
 
 def init_stack_cache(cfg, pattern, n_layers: int, batch: int,
                      cache_len: int) -> dict:
+    if _lead(cfg, pattern):
+        raise NotImplementedError(
+            f"{cfg.name}: leading dense layers have no decode cache yet")
     repeats, tail = _pattern_split(n_layers, pattern)
     unit = {f"b{j}": _init_block_cache(kind, cfg, batch, cache_len)
             for j, kind in enumerate(pattern)}
@@ -307,15 +364,34 @@ def apply_stack(params, sites, x, *, cfg, pattern, policy, seed, step,
                 positions, caches=None, enc_out=None, enc_len=None,
                 prefix_len=None):
     """Returns (x, new_sites, new_caches, metrics)."""
-    repeats, tail = _pattern_split(_stack_depth(cfg, pattern), pattern)
+    lead = _lead(cfg, pattern)
+    repeats, tail = _pattern_split(_stack_depth(cfg, pattern) - lead, pattern)
+    lead_metrics = zero_metrics(cfg)
+
+    new_lead_sites = {}
+    for j in range(lead):
+        key = f"l{j}"
+        block = functools.partial(
+            _apply_block, "attn", cfg=cfg, policy=policy, step=step,
+            positions=positions, enc_out=enc_out, enc_len=enc_len,
+            prefix_len=prefix_len)
+        if cfg.remat:
+            block = jax.checkpoint(block)
+        x, new_lead_sites[key], _, m = block(
+            params["lead"][key], sites["lead"][key], x,
+            seed=seed + j * _SEED_STRIDE)
+        lead_metrics = {k: lead_metrics[k] + m[k] for k in lead_metrics}
 
     def unit_fn(x, unit_params, unit_sites, unit_caches, ridx):
         x = hint(x, "batch", "seq", "embed")
         new_sites, new_caches = {}, {}
-        met = {"aux_loss": jnp.float32(0.0), "z_loss": jnp.float32(0.0)}
+        met = zero_metrics(cfg)
         for j, kind in enumerate(pattern):
             key = f"b{j}"
-            layer_seed = seed + (ridx * len(pattern) + j) * _SEED_STRIDE
+            layer = ridx * len(pattern) + j
+            if lead:
+                layer = layer + lead
+            layer_seed = seed + layer * _SEED_STRIDE
             x, ns, nc, m = _apply_block(
                 kind, unit_params[key], unit_sites[key], x, cfg=cfg,
                 policy=policy, seed=layer_seed, step=step,
@@ -341,7 +417,7 @@ def apply_stack(params, sites, x, *, cfg, pattern, policy, seed, step,
         x, ns, nc, met = unit_fn(x, unit_params, unit_sites, unit_caches, ridx)
         return x, (ns, nc, met)
 
-    metrics = {"aux_loss": jnp.float32(0.0), "z_loss": jnp.float32(0.0)}
+    metrics = zero_metrics(cfg)
     new_block_sites, new_block_caches = {}, {}
     if repeats > 0:
         xs = (params["blocks"], sites["blocks"], jnp.arange(repeats)) \
@@ -350,11 +426,13 @@ def apply_stack(params, sites, x, *, cfg, pattern, policy, seed, step,
         x, (new_block_sites, new_block_caches, mets) = jax.lax.scan(
             body, x, xs)
         metrics = jax.tree_util.tree_map(jnp.sum, mets)
+    if lead:
+        metrics = {k: lead_metrics[k] + metrics[k] for k in metrics}
 
     new_tail_sites, new_tail_caches = {}, {}
     for j, kind in enumerate(tail):
         key = f"t{j}"
-        layer_seed = seed + (repeats * len(pattern) + j) * _SEED_STRIDE
+        layer_seed = seed + (lead + repeats * len(pattern) + j) * _SEED_STRIDE
         x, ns, nc, m = _apply_block(
             kind, params["tail"][key], sites["tail"][key], x, cfg=cfg,
             policy=policy, seed=layer_seed, step=step, positions=positions,
@@ -368,6 +446,8 @@ def apply_stack(params, sites, x, *, cfg, pattern, policy, seed, step,
     new_sites = {"blocks": new_block_sites, "tail": new_tail_sites}
     new_caches = None if caches is None else {"blocks": new_block_caches,
                                               "tail": new_tail_caches}
+    if lead:
+        new_sites["lead"] = new_lead_sites
     return x, new_sites, new_caches, metrics
 
 

@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from repro.core import backend as qbackend
 from repro.core import qlinear
 from repro.core.policy import QuantPolicy
-from repro.models import model
+from repro.models import model, moe, transformer
 from repro.optim import apply_updates, clip_by_global_norm
 
 PyTree = Any
@@ -71,6 +71,8 @@ def make_train_step(
     telemetry widening and checkpointing need no backend awareness.
     """
     qbackend.validate(policy)
+    bias_rate = moe.BIAS_UPDATE_RATE \
+        if cfg.moe and cfg.moe.scoring == "sigmoid" else 0.0
 
     def micro(params, quant, mb, step, midx):
         seed = step * 262144 + midx * 8192
@@ -106,9 +108,8 @@ def make_train_step(
             zeros_g = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), params)
             zeros_s = qlinear.zero_stats_like(quant)
-            zeros_m = {"aux_loss": 0.0, "z_loss": 0.0, "z_loss_head": 0.0,
-                       "nll": 0.0}
-            zeros_m = jax.tree_util.tree_map(jnp.float32, zeros_m)
+            zeros_m = dict(transformer.zero_metrics(cfg),
+                           z_loss_head=jnp.float32(0.0), nll=jnp.float32(0.0))
             (grads, stats, loss, met), _ = jax.lax.scan(
                 body, (zeros_g, zeros_s, jnp.float32(0.0), zeros_m),
                 (mbs, jnp.arange(grad_accum)))
@@ -120,6 +121,16 @@ def make_train_step(
         if compress is not None:
             grads, stats = compress(grads, stats)
 
+        # A sigmoid router's correction bias is no weight: its cotangent is
+        # the step's routing load (models/moe.py), which moves it by the
+        # aux-loss-free rule after the optimizer, in place of an update.
+        loads = grads
+        if bias_rate:
+            grads = jax.tree_util.tree_map_with_path(
+                lambda path, g: jnp.zeros_like(g)
+                if getattr(path[-1], "key", None) == "router_bias" else g,
+                grads)
+
         metrics = dict(met)
         if clip_norm is not None:
             grads, gnorm = clip_by_global_norm(grads, clip_norm)
@@ -128,6 +139,9 @@ def make_train_step(
         lr = lr_schedule(step)
         updates, new_opt = optimizer.update(grads, state["opt"], params, lr)
         new_params = apply_updates(params, updates)
+        if bias_rate:
+            new_params = moe.update_router_bias(new_params, params, loads,
+                                                bias_rate)
         new_quant = qlinear.update_quant_state(policy, quant, stats)
 
         metrics["loss"] = loss

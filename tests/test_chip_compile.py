@@ -130,3 +130,40 @@ def test_int8_attention_compiles(chip, block):
 @pytest.mark.parametrize("block", [None, *tuning.ATTN_CANDIDATES])
 def test_int8_attention_causal_compiles(chip, block):
     _compile_attention(chip, block, "causal", 0)
+
+
+# Moonlight-16B-A3B widths: latent attention's query-key heads of 192 and
+# value heads of 128 at its context 8192 (causal, 16 heads), and the
+# grouped expert contractions over 8 held experts of 2048 x 1408 with the
+# worst-case buffer of 8192 tokens x 6 assignments (plus each group's
+# padding to a row tile).
+MOE_SEQ, MOE_HEADS, QK_DIM, V_DIM = 8192, 16, 192, 128
+MOE_HELD, MOE_D, MOE_F, MOE_ROWS = 8, 2048, 1408, 8192 * 6
+
+
+def test_int8_attention_latent_heads_compiles(chip):
+    bq, bkv = tuning.attention_block(MOE_SEQ, MOE_SEQ, QK_DIM)
+    sched = int8_attention.make_schedule(
+        sq=MOE_SEQ, skv=MOE_SEQ, hd=QK_DIM, hdv=V_DIM, bq=bq, bkv=bkv,
+        groups=1, mode="causal", sm_scale=QK_DIM ** -0.5)
+    _compile_has_kernel(
+        lambda q, k, v, regs, kvlen: ops.int8_attention_fp(
+            q, k, v, regs, kvlen, sched=sched),
+        chip((MOE_HEADS, MOE_SEQ, QK_DIM), "uint8"),
+        chip((MOE_HEADS, MOE_SEQ, QK_DIM), "int8"),
+        chip((MOE_HEADS, MOE_SEQ, V_DIM), "int8"), chip((1, 8), "float32"),
+        chip((1, 1), "int32"))
+
+
+@pytest.mark.parametrize("k,n", [(MOE_D, MOE_F), (MOE_F, MOE_D)],
+                         ids=["up", "down"])
+def test_int8_grouped_matmul_compiles(chip, k, n):
+    from repro.kernels import int8_grouped_matmul as gmm
+    tiles = MOE_ROWS // gmm.GMM_ROWS + MOE_HELD
+    _compile_has_kernel(
+        lambda x, w, zp, alpha, g, r, live, sizes: ops.int8_gmm_fp(
+            x, w, zp, alpha, gmm.GmmTiles(g, r, live, sizes)),
+        chip((tiles * gmm.GMM_ROWS, k), "uint8"),
+        chip((MOE_HELD, k, n), "int8"), chip((), "float32"),
+        chip((), "float32"), chip((tiles,), "int32"), chip((tiles,), "int32"),
+        chip((1,), "int32"), chip((MOE_HELD,), "int32"))
