@@ -686,7 +686,10 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
 
     The block plan is resolved ONCE here (``kernels.tuning``, env
     ``REPRO_ATTN_BLOCK``) and baked into the static schedule both
-    backends replay — tile choice changes speed, never results.
+    backends replay — tile choice changes speed, never results.  So is
+    which probability statistics the core computes: only the (min, max)
+    the range update reads, unless the policy's telemetry reads the
+    clip/SQNR counters too.
     """
     b, s, kvh, g, hd = q.shape
     skv, hdv = k.shape[1], v.shape[-1]
@@ -724,7 +727,8 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
         sched = mod.make_schedule(
             sq=s, skv=skv, hd=hd, bq=bq, bkv=bkv, groups=g, mode=mode,
             window=int(window or 0), prefix_len=int(prefix_len or 0),
-            sm_scale=float(scale), hdv=hdv)
+            sm_scale=float(scale), hdv=hdv,
+            stats="full" if policy.telemetry.enabled else "range")
 
         # Head-major flatten (exact: transposes/reshapes move values, not
         # bits): q -> [B*KV*G, S, hd], k/v -> [B*KV, Skv, hd].  The outer

@@ -12,9 +12,13 @@ kv block) tile is:
     -> requantize p with the PRE-COMPUTED [p_lo, p_hi] registers
     -> int8 PV (MXU, int32 accumulate)
 
-entirely in VMEM, while the same resident tile is reduced to the (min,
-max, clip, n, err, sig) partials that feed the next step's range update —
-no second pass, no score tile in HBM.
+entirely in VMEM, while the same resident tile is reduced to the
+statistics partials — no second pass, no score tile in HBM.  Which ones is
+the schedule's ``stats``: ``"range"`` folds only (min, max), the two that
+feed the next step's range update; ``"full"`` (a policy with telemetry on)
+adds the (clip, n, err, sig) counters.  Under ``"range"`` slots 2-5 keep
+their initial 0, and the err/sig tree sums — the longest serial chain of
+a tile — are not traced at all.
 
 Bit-parity contract (the PR-3/PR-5 convention, extended to attention)
 ---------------------------------------------------------------------
@@ -26,8 +30,11 @@ for the fp statistics.  Every contraction is exact in int32, every fp
 reduction is either exact in any association (min/max, integer-valued
 counts) or order-pinned, and the per-block fp recurrence is shared code
 (``_scores_to_probs`` / ``_accumulate`` / ``_stats_update``) — so kernel
-and reference agree bit-for-bit on outputs, softmax registers and the
-statistics partials.  ``reduce_pstats`` is the single shared reduction of
+and reference agree bit-for-bit on outputs, softmax registers and all six
+statistics slots, in both ``stats`` modes (slots 2-5 are computed only
+under ``"full"``, i.e. with telemetry on).  The two modes share the whole
+recurrence: outputs, softmax registers and (min, max) are bit-equal
+between them.  ``reduce_pstats`` is the single shared reduction of
 the per-(head, q block) partials for BOTH backends.
 
 Layout: q is uint8 ``[BH, sq, hd]`` with ``BH = B * KV * G`` (GQA
@@ -60,6 +67,8 @@ from . import STATS_TILE, platform, stats_tile, stats_values
 NEG_INF = -1e30           # matches models/attention.py: finite, NaN-free
 P_SPEC = QuantSpec(bits=8, symmetric=False)   # probability grid [0, 255]
 STAT_SLOTS = 6            # (pmin, pmax, clip, n, err, sig)
+RANGE_SLOTS = 2           # (pmin, pmax): what the range update reads
+STAT_MODES = ("range", "full")
 
 MASK_MODES = ("causal", "sliding", "prefix", "cross", "bidir")
 
@@ -83,6 +92,7 @@ class AttnSchedule:
     prefix_len: int        # prefix-LM boundary (0 when unused)
     sm_scale: float        # softmax scale (head_dim ** -0.5)
     width: int             # kv blocks walked per q block (the grid's last axis)
+    stats: str = "full"    # range: (min, max) only | full: all six slots
 
     @property
     def nq(self) -> int:
@@ -91,6 +101,11 @@ class AttnSchedule:
     @property
     def nkv(self) -> int:
         return -(-self.skv // self.bkv)
+
+    @property
+    def stat_slots(self) -> int:
+        """Statistics slots each tile computes (the rest stay at 0)."""
+        return RANGE_SLOTS if self.stats == "range" else STAT_SLOTS
 
     @property
     def visited_blocks(self) -> int:
@@ -105,7 +120,7 @@ class AttnSchedule:
 def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
                   groups: int, mode: str, window: int = 0,
                   prefix_len: int = 0, sm_scale: float,
-                  hdv: int = 0) -> AttnSchedule:
+                  hdv: int = 0, stats: str = "full") -> AttnSchedule:
     """Resolve block sizes and the per-q-block kv visitation width.
 
     For every mode but ``sliding`` each q block walks all kv blocks.
@@ -113,10 +128,13 @@ def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
     number of kv blocks any q block's window can touch — O(S * w) grid
     steps instead of O(S^2).  In every mode the block-level ``visited``
     predicate then skips the walk's fully-masked blocks.  ``hdv`` is the
-    value head dim (0: the query-key ``hd``).
+    value head dim (0: the query-key ``hd``); ``stats`` which statistics
+    each tile computes (``"range"``: min and max only).
     """
     if mode not in MASK_MODES:
         raise ValueError(f"unknown mask mode {mode!r}; expected {MASK_MODES}")
+    if stats not in STAT_MODES:
+        raise ValueError(f"unknown stats {stats!r}; expected {STAT_MODES}")
     if mode == "sliding" and window <= 0:
         raise ValueError("sliding mode requires window > 0")
     bq = max(1, min(int(bq), sq))
@@ -142,7 +160,7 @@ def make_schedule(*, sq: int, skv: int, hd: int, bq: int, bkv: int,
     return AttnSchedule(sq=sq, skv=skv, hd=hd, hdv=hdv, bq=bq, bkv=bkv,
                         groups=groups,
                         mode=mode, window=int(window), prefix_len=int(prefix_len),
-                        sm_scale=float(sm_scale), width=width)
+                        sm_scale=float(sm_scale), width=width, stats=stats)
 
 
 def _kv_block_span(i, sched: AttnSchedule, xp=jnp):
@@ -261,11 +279,11 @@ def _tree_sum_flat(v):
 def _scores_to_probs(acc_qk, mask, m_prev, alpha_qk, scale_p, zp_p):
     """int32 QK^T accumulator tile -> quantized probabilities.
 
-    Returns ``(rp, p, p_hat, m_new, corr)`` where ``rp`` is the
+    Returns ``(rp, p, p_int, m_new, corr)`` where ``rp`` is the
     zero-point-corrected int32 probability image (masked entries exactly
     0, so block-padding garbage contributes exactly nothing to PV), ``p``
-    the fp probabilities the statistics observe and ``p_hat`` their
-    dequantized image (for the SQNR telemetry).
+    the fp probabilities the statistics observe and ``p_int`` their grid
+    image (dequantized only for the SQNR telemetry).
     """
     s = _fence(alpha_qk * acc_qk.astype(jnp.float32))
     s = jnp.where(mask, s, NEG_INF)
@@ -278,9 +296,8 @@ def _scores_to_probs(acc_qk, mask, m_prev, alpha_qk, scale_p, zp_p):
     p_int = jnp.clip(jnp.round(p / scale_p + zp_p),
                      float(P_SPEC.int_min), float(P_SPEC.int_max))
     rp = p_int.astype(jnp.int32) - zp_p.astype(jnp.int32)
-    p_hat = (p_int - zp_p) * scale_p
     corr = jnp.exp(m_prev - m_new)
-    return rp, p, p_hat, m_new, corr
+    return rp, p, p_int, m_new, corr
 
 
 def _accumulate(acc_prev, l_prev, corr, acc_pv, rp, alpha_pv, scale_p):
@@ -295,16 +312,23 @@ def _accumulate(acc_prev, l_prev, corr, acc_pv, rp, alpha_pv, scale_p):
 _STAT_FOLD = (jnp.minimum, jnp.maximum, jnp.add, jnp.add, jnp.add, jnp.add)
 
 
-def _tile_stats(p, p_hat, sv, p_lo, p_hi):
-    """One tile's (pmin, pmax, clip, n, err, sig) contributions.
+def _tile_stats(p, p_int, sv, regs, slots):
+    """One tile's first ``slots`` of (pmin, pmax, clip, n, err, sig).
 
-    ``sv`` masks to in-bounds entries (rows < sq, cols < skv); min/max and
-    the integer-valued counters are exact in any association, err/sig use
-    the pinned pairwise tree sum.
+    ``regs`` is ``(scale_p, zp_p, p_lo, p_hi)``.  ``sv`` masks to in-bounds
+    entries (rows < sq, cols < skv); min/max and the integer-valued
+    counters are exact in any association, err/sig use the pinned pairwise
+    tree sum.  With ``slots == RANGE_SLOTS`` only the masked min and max
+    are traced: the counters, the dequantized image and both tree sums
+    exist only when telemetry reads them.
     """
     big = jnp.float32(jnp.finfo(jnp.float32).max)
     pmn = jnp.min(jnp.where(sv, p, big), axis=(-2, -1))
     pmx = jnp.max(jnp.where(sv, p, -big), axis=(-2, -1))
+    if slots == RANGE_SLOTS:
+        return pmn, pmx
+    scale_p, zp_p, p_lo, p_hi = regs
+    p_hat = (p_int - zp_p) * scale_p
     clip = jnp.sum(jnp.where(sv & ((p < p_lo) | (p > p_hi)), 1.0, 0.0),
                    axis=(-2, -1))
     cnt = jnp.sum(jnp.where(sv, 1.0, 0.0), axis=(-2, -1))
@@ -313,12 +337,15 @@ def _tile_stats(p, p_hat, sv, p_lo, p_hi):
     return pmn, pmx, clip, cnt, err, sig
 
 
-def _stats_update(st, p, p_hat, sv, p_lo, p_hi):
+def _stats_update(st, p, p_int, sv, regs, slots):
     """Fold one tile into ``[..., STAT_SLOTS]`` partials (the reference's
-    layout; the kernel folds the same values into its stats tile)."""
-    inc = _tile_stats(p, p_hat, sv, p_lo, p_hi)
+    layout; the kernel folds the same values into its stats tile).  Slots
+    past ``slots`` keep their values."""
+    inc = _tile_stats(p, p_int, sv, regs, slots)
     return jnp.stack([fold(st[..., r], v) for r, (fold, v)
-                      in enumerate(zip(_STAT_FOLD, inc))], axis=-1)
+                      in enumerate(zip(_STAT_FOLD, inc))]
+                     + [st[..., r] for r in range(slots, STAT_SLOTS)],
+                     axis=-1)
 
 
 def _stats_init_values():
@@ -334,9 +361,11 @@ def _stats_init(shape=()):
 
 def reduce_pstats(partials: jax.Array):
     """Reduce the ``[BH, nq, 6]`` per-(head, q block) partials to the
-    site-level (mn, mx, clip, n, err, sig).  SHARED by both backends (the
-    partials are bit-identical, so one reduction keeps them identical):
-    min/max/counts exact in any association, err/sig order-pinned."""
+    site-level (mn, mx, clip, n, err, sig), the last four 0 under a
+    ``"range"`` schedule (XLA drops the outputs no one reads).  SHARED by
+    both backends (the partials are bit-identical, so one reduction keeps
+    them identical): min/max/counts exact in any association, err/sig
+    order-pinned."""
     mn = jnp.min(partials[..., 0])
     mx = jnp.max(partials[..., 1])
     clip = jnp.sum(partials[..., 2])
@@ -363,7 +392,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
 
     Both identities are exact in int32, so the accumulators equal the
     reference's int32 einsums of the zero-point-corrected images.
-    ``st_sc`` is a ``STATS_TILE`` whose row ``r`` holds stat slot ``r``."""
+    ``st_sc`` is a ``STATS_TILE`` whose row ``r`` holds stat slot ``r``;
+    only the schedule's first ``stat_slots`` rows are folded."""
     S = sched
     i = pl.program_id(1)
     t = pl.program_id(2)
@@ -400,7 +430,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
             jnp.int32, (S.bq, S.bkv), 1)
         mask = _element_mask(q_pos, k_pos, kvlen, S)
 
-        rp, p, p_hat, m_new, corr = _scores_to_probs(
+        rp, p, p_int, m_new, corr = _scores_to_probs(
             acc_qk, mask, m_sc[...], alpha_qk, scale_p, zp_p)
         zp_p_i = zp_p.astype(jnp.int32)
         p_s8 = (rp + (zp_p_i - 128)).astype(jnp.int8)
@@ -416,7 +446,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, ksum_ref, regs_ref, kvlen_ref,
         m_sc[...] = m_new
 
         sv = (q_pos < S.sq) & (k_pos < S.skv)
-        inc = _tile_stats(p, p_hat, sv, p_lo, p_hi)
+        inc = _tile_stats(p, p_int, sv, (scale_p, zp_p, p_lo, p_hi),
+                          S.stat_slots)
         st = st_sc[...]
         rows = jax.lax.broadcasted_iota(jnp.int32, STATS_TILE, 0)
         new = st
@@ -548,14 +579,15 @@ def attention_core_reference(q_u8, k_i8, v_i8, regs, kvlen, *,
                 jnp.int32, (S.bq, S.bkv), 1)
             mask = _element_mask(q_pos, k_pos, kvl, S)[None, None]
 
-            rp, p, p_hat, m_new, corr = _scores_to_probs(
+            rp, p, p_int, m_new, corr = _scores_to_probs(
                 acc_qk, mask, m, alpha_qk, scale_p, zp_p)
             acc_pv = jnp.einsum("zgqk,zkh->zgqh", rp, rv,
                                 preferred_element_type=jnp.int32)
             acc_n, l_n = _accumulate(acc, l, corr, acc_pv, rp,
                                      alpha_pv, scale_p)
             sv = ((q_pos < S.sq) & (k_pos < S.skv))[None, None]
-            st_n = _stats_update(st, p, p_hat, sv, p_lo, p_hi)
+            st_n = _stats_update(st, p, p_int, sv,
+                                 (scale_p, zp_p, p_lo, p_hi), S.stat_slots)
 
             vis = _block_visited(i, ki, S)
             if vis is not None:

@@ -14,6 +14,9 @@ Also covered here:
   * the sliding-window block-local fast path (grid width < nkv) and the
     skip of fully masked tiles, counted by ``AttnSchedule.visited_blocks``,
   * probability-site clip/SQNR counters and the widen guard,
+  * the statistics mode: a telemetry-off policy runs the ``"range"``
+    core (min and max only), bit-equal on every value training reads to
+    the ``"full"`` core a telemetry-on policy runs,
   * ``qattn_int8_*`` / ``k_attn_*`` named scopes in compiled HLO,
   * the fused jitted train step never materializes the full fp score
     tile (checked on the compiled HLO via ``launch.hlo_cost``).
@@ -181,6 +184,8 @@ def _trace_qattention(policy):
     k = jax.random.normal(jax.random.PRNGKey(1), (B, 16, NKV, HD))
     v = jax.random.normal(jax.random.PRNGKey(2), (B, 16, NKV, HD))
     sites = attn.init_attention_sites()["core"]
+    if policy.stat_width != 3:
+        sites = tmetrics.widen_state(sites, policy.stat_width)
 
     def f(q, k, v):
         out, stats = backend.qattention(policy, q, k, v, sites,
@@ -311,6 +316,93 @@ def test_sliding_skip_outside_window_is_exact(monkeypatch):
         for name, x, y in zip(("out", "ml"), skip[0], every):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                           err_msg=f"every tile: {name}")
+
+
+# ---------------------------------------------------------------------------
+# Statistics mode: "range" folds (min, max) only, "full" all six slots.
+# ---------------------------------------------------------------------------
+STATS_MODE_CASES = MODE_CASES + [("bidir", {})]
+
+
+@pytest.mark.parametrize("mode,kw", STATS_MODE_CASES,
+                         ids=[m for m, _ in STATS_MODE_CASES])
+def test_range_stats_core(mode, kw, monkeypatch):
+    """The "range" core equals the "full" one on out, the softmax
+    residuals and (min, max), leaves slots 2-5 at their initial 0, never
+    traces the err/sig tree sums, and kernel and reference agree on it
+    bit for bit.  seq 20 leaves padded rows and columns in the last
+    blocks, so the in-bounds mask of the statistics is exercised."""
+    seq, g = 20, 2
+    args = _core_inputs(seq, bh=2 * g, zb=2, seed=5)
+    base = dict(sq=seq, skv=seq, hd=HD, bq=8, bkv=8, groups=g, mode=mode,
+                sm_scale=1.0, **kw)
+    rng = make_schedule(stats="range", **base)
+    full = make_schedule(stats="full", **base)
+    assert (rng.stat_slots, full.stat_slots) == (2, ia.STAT_SLOTS)
+
+    sums = []
+    orig = ia._tree_sum_last2
+    monkeypatch.setattr(ia, "_tree_sum_last2",
+                        lambda v: sums.append(1) or orig(v))
+    r_kernel, r_ref = _core_both(rng, args)
+    assert not sums, "a range core traced the err/sig tree sums"
+    f_kernel, f_ref = _core_both(full, args)
+    assert sums
+
+    _assert_core_equal(r_kernel, r_ref, f"{mode} range: kernel vs reference")
+    _assert_core_equal(f_kernel, f_ref, f"{mode} full: kernel vs reference")
+    for name, x, y in zip(("out", "ml"), r_kernel, f_kernel):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
+                                      err_msg=f"{mode} range vs full: {name}")
+    r_st, f_st = np.asarray(r_kernel[2]), np.asarray(f_kernel[2])
+    np.testing.assert_array_equal(r_st[..., :2], f_st[..., :2],
+                                  err_msg=f"{mode} range vs full: min, max")
+    np.testing.assert_array_equal(r_st[..., 2:], 0.0)
+    assert np.all(f_st[..., 3] > 0)            # full counts every tile's n
+
+
+def _traced_schedules(policy, monkeypatch):
+    scheds = []
+    orig = ia.make_schedule
+    monkeypatch.setattr(
+        ia, "make_schedule",
+        lambda **kw: scheds.append(orig(**kw)) or scheds[-1])
+    _trace_qattention(policy)
+    return scheds
+
+
+@pytest.mark.parametrize("bk", ["simulated", "fused"])
+def test_qattention_stats_follow_telemetry(bk, monkeypatch):
+    """The policy decides the core's statistics: min and max only with
+    telemetry off, all six slots with it on."""
+    off = QuantPolicy.w8a8g8(backend=bk)
+    (sched,) = _traced_schedules(off, monkeypatch)
+    assert (sched.stats, sched.stat_slots) == ("range", 2)
+    (sched,) = _traced_schedules(off.with_telemetry(), monkeypatch)
+    assert (sched.stats, sched.stat_slots) == ("full", ia.STAT_SLOTS)
+
+
+@pytest.mark.parametrize("mode,kw", MODE_CASES,
+                         ids=[m for m, _ in MODE_CASES])
+def test_telemetry_changes_no_training_value(mode, kw, monkeypatch):
+    """Two fused steps with telemetry off ("range" core) and on ("full"
+    core): losses, params, grads and the range (min, max) of every quant
+    state leaf are bit-equal."""
+    monkeypatch.setenv("REPRO_ATTN_BLOCK", "8,8")
+    tuning.clear_cache()
+    off_policy = QuantPolicy.w8a8g8(backend="fused")
+    off = _run_steps(off_policy, mode, **kw)
+    on = _run_steps(off_policy.with_telemetry(), mode, **kw)
+    for a, b in zip(off[0], on[0]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=f"{mode}: loss")
+    _assert_tree_equal(off[1], on[1], f"{mode}: params")
+    _assert_tree_equal(off[3], on[3], f"{mode}: grads")
+    rng = slice(tconfig.QMIN, tconfig.QMAX + 1)
+    _assert_tree_equal(
+        jax.tree_util.tree_map(lambda x: x[..., rng], off[2]),
+        jax.tree_util.tree_map(lambda x: x[..., rng], on[2]),
+        f"{mode}: quant state range")
 
 
 # ---------------------------------------------------------------------------

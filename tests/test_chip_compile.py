@@ -107,11 +107,11 @@ def test_int8_matmul_fused_compiles(chip):
         chip((D_FF,), "float32"))
 
 
-def _compile_attention(chip, block, mode, window):
+def _compile_attention(chip, block, mode, window, stats):
     bq, bkv = block or tuning.attention_block(SEQ, SEQ, HEAD_DIM)
     sched = int8_attention.make_schedule(
         sq=SEQ, skv=SEQ, hd=HEAD_DIM, bq=bq, bkv=bkv, groups=GROUPS,
-        mode=mode, window=window, sm_scale=HEAD_DIM ** -0.5)
+        mode=mode, window=window, sm_scale=HEAD_DIM ** -0.5, stats=stats)
     bh, zb = BATCH * N_KV * GROUPS, BATCH * N_KV
     _compile_has_kernel(
         lambda q, k, v, regs, kvlen: ops.int8_attention_fp(
@@ -121,15 +121,28 @@ def _compile_attention(chip, block, mode, window):
         chip((1, 1), "int32"))
 
 
-@pytest.mark.parametrize("block", [None, *tuning.ATTN_CANDIDATES])
-def test_int8_attention_compiles(chip, block):
-    _compile_attention(chip, block, "sliding", WINDOW)
+# Every block in both statistics modes: "full" (telemetry on, all six
+# slots; these cases keep their ids from before "range" existed) and
+# "range" (min and max only, the kernel every telemetry-off step runs).
+_ATTN_BLOCKS = [None, *tuning.ATTN_CANDIDATES]
+ATTN_CASES = [
+    *(pytest.param(b, "full", id="None" if b is None else f"block{i}")
+      for i, b in enumerate(_ATTN_BLOCKS)),
+    *(pytest.param(b, "range",
+                   id="range-" + ("tuned" if b is None else "%dx%d" % b))
+      for b in _ATTN_BLOCKS),
+]
+
+
+@pytest.mark.parametrize("block,stats", ATTN_CASES)
+def test_int8_attention_compiles(chip, block, stats):
+    _compile_attention(chip, block, "sliding", WINDOW, stats)
 
 
 # The skip predicate and the clamped kv index maps in the causal mode.
-@pytest.mark.parametrize("block", [None, *tuning.ATTN_CANDIDATES])
-def test_int8_attention_causal_compiles(chip, block):
-    _compile_attention(chip, block, "causal", 0)
+@pytest.mark.parametrize("block,stats", ATTN_CASES)
+def test_int8_attention_causal_compiles(chip, block, stats):
+    _compile_attention(chip, block, "causal", 0, stats)
 
 
 # Moonlight-16B-A3B widths: latent attention's query-key heads of 192 and
@@ -141,11 +154,12 @@ MOE_SEQ, MOE_HEADS, QK_DIM, V_DIM = 8192, 16, 192, 128
 MOE_HELD, MOE_D, MOE_F, MOE_ROWS = 8, 2048, 1408, 8192 * 6
 
 
-def test_int8_attention_latent_heads_compiles(chip):
+@pytest.mark.parametrize("stats", int8_attention.STAT_MODES)
+def test_int8_attention_latent_heads_compiles(chip, stats):
     bq, bkv = tuning.attention_block(MOE_SEQ, MOE_SEQ, QK_DIM)
     sched = int8_attention.make_schedule(
         sq=MOE_SEQ, skv=MOE_SEQ, hd=QK_DIM, hdv=V_DIM, bq=bq, bkv=bkv,
-        groups=1, mode="causal", sm_scale=QK_DIM ** -0.5)
+        groups=1, mode="causal", sm_scale=QK_DIM ** -0.5, stats=stats)
     _compile_has_kernel(
         lambda q, k, v, regs, kvlen: ops.int8_attention_fp(
             q, k, v, regs, kvlen, sched=sched),
