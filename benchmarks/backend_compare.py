@@ -93,7 +93,7 @@ def time_backend_cnn(backend: str, steps: int, warmup: int = 1):
 
 def time_backend_attn(backend: str, steps: int, warmup: int = 1):
     """One GQA attention layer as a toy train loop: every step runs the
-    backend-dispatched int8 attention core (``backend.qattention``) plus
+    backend-dispatched int8 attention core (``backend.attention``) plus
     the q/k/v/o projection sites, with estimator updates between steps."""
     import jax.numpy as jnp
 

@@ -95,7 +95,7 @@ def attn_site_study(estimators, *, steps, seeds):
     reports the final loss per estimator plus, for the static hindsight
     run, one row per core site (q/k/v logits, softmax probabilities) with
     its learned EMA range — the sites the int8 flash kernel consumes
-    (``backend.qattention``).  For the per-site rows the metric columns
+    (``backend.attention``).  For the per-site rows the metric columns
     carry [range_lo, range_hi] instead of [mean, std]."""
     import jax
     import jax.numpy as jnp

@@ -52,7 +52,7 @@ def main():
         cfg = dataclasses.replace(
             configs.get_reduced("starcoder2-3b"), n_layers=12, d_model=768,
             n_heads=12, n_kv=4, head_dim=64, d_ff=3072, vocab=32768,
-            sliding_window=256, loss_chunk=64, q_chunk=128, kv_chunk=128)
+            sliding_window=256, loss_chunk=64)
         seq, batch, steps = 256, 16, 300
     else:
         cfg = configs.get_reduced("starcoder2-3b")

@@ -102,9 +102,6 @@ class ArchConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
     cache_dtype: str = "bfloat16"
-    q_chunk: int = 2048
-    kv_chunk: int = 1024
-    dense_attn_max: int = 4096   # dense score tile up to this seq length
     loss_chunk: int = 512
     logit_z_coef: float = 0.0
     remat: bool = True

@@ -60,7 +60,7 @@ CONFIG = ArchConfig(
 def reduced() -> ArchConfig:
     return dataclasses.replace(
         CONFIG, n_layers=3, d_model=64, n_heads=4, n_kv=4, head_dim=24,
-        d_ff=96, vocab=512, loss_chunk=16, q_chunk=16, kv_chunk=16,
+        d_ff=96, vocab=512, loss_chunk=16,
         kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
         v_head_dim=16,
         moe=dataclasses.replace(CONFIG.moe, n_experts=16, top_k=4,

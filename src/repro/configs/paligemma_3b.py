@@ -39,7 +39,7 @@ def reduced() -> ArchConfig:
     return dataclasses.replace(
         CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv=1, head_dim=16,
         d_ff=128, vocab=512, frontend_dim=24, n_patches=8, loss_chunk=8,
-        q_chunk=16, kv_chunk=16, grad_accum=(("train_4k", 1),))
+        grad_accum=(("train_4k", 1),))
 
 
 register(CONFIG, reduced)

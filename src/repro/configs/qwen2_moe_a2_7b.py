@@ -35,7 +35,7 @@ CONFIG = ArchConfig(
 def reduced() -> ArchConfig:
     return dataclasses.replace(
         CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv=4, head_dim=16,
-        d_ff=64, vocab=512, loss_chunk=16, q_chunk=16, kv_chunk=16,
+        d_ff=64, vocab=512, loss_chunk=16,
         moe=MoeSpec(n_experts=8, top_k=2, d_expert=64, n_shared=1,
                     d_shared=128, mlp_kind="swiglu"),
         grad_accum=(("train_4k", 1),))

@@ -37,7 +37,7 @@ def reduced() -> ArchConfig:
     return dataclasses.replace(
         CONFIG, n_layers=2, enc_layers=2, d_model=64, n_heads=4, n_kv=4,
         head_dim=16, d_ff=128, vocab=512, frontend_dim=16, loss_chunk=16,
-        q_chunk=16, kv_chunk=16, grad_accum=(("train_4k", 1),))
+        grad_accum=(("train_4k", 1),))
 
 
 register(CONFIG, reduced)

@@ -35,8 +35,8 @@ CONFIG = ArchConfig(
 def reduced() -> ArchConfig:
     return dataclasses.replace(
         CONFIG, n_layers=2, d_model=64, n_heads=4, n_kv=2, head_dim=16,
-        d_ff=128, vocab=512, sliding_window=16, loss_chunk=16, q_chunk=16,
-        kv_chunk=16, grad_accum=(("train_4k", 1),))
+        d_ff=128, vocab=512, sliding_window=16, loss_chunk=16,
+        grad_accum=(("train_4k", 1),))
 
 
 register(CONFIG, reduced)

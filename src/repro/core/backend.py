@@ -137,6 +137,11 @@ def site_key(seed: jax.Array, salt: int) -> jax.Array:
     return jax.random.PRNGKey(s.astype(jnp.int32))
 
 
+def stats_zeros(policy) -> jax.Array:
+    """A "site not visited" stats vector of the policy's stat width."""
+    return jnp.zeros((policy.stat_width,), jnp.float32)
+
+
 def float0_like(x):
     return np.zeros(np.shape(x), dtype=jax.dtypes.float0)
 
@@ -576,6 +581,7 @@ def qmatmul(policy, espec: str, xq: jax.Array, xqt: Optional[QTensor],
 # (symmetric int8), and the softmax PROBABILITIES — feed a flash-style
 # int8 core; the probability statistics come back from the kernel's
 # resident tiles, so the site performs zero standalone min/max reductions.
+# Every other policy runs the fp core on the same block plan.
 # ---------------------------------------------------------------------------
 KV_SPEC = quant.QuantSpec(bits=8, symmetric=True, stochastic=False)
 P_SPEC = quant.QuantSpec(bits=8, symmetric=False, stochastic=False)
@@ -595,7 +601,7 @@ def qattention_eligible(policy) -> bool:
     probability range is consumed *mid-kernel*, before the tensor exists,
     so — unlike every other site — it has no dynamic first-batch fallback
     (its leaf is initialized a-priori to the softmax codomain [0, 1]).
-    Dynamic policies keep the fp einsum attention path.
+    Every other policy runs the fp core (``attention``).
     """
     return bool(
         policy.enabled and policy.quantize_acts
@@ -672,27 +678,67 @@ def _make_qattention(sched, fused: bool):
     return qat
 
 
-def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
-               sites: dict, *, mode: str, window=None, prefix_len=None,
-               kv_len=None, scale: float, step: jax.Array):
-    """Backend-dispatched quantized attention core.
+def attention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
+              sites: dict, *, mode: str, window=None, prefix_len=None,
+              kv_len=None, scale: float, step: jax.Array):
+    """The attention core site: the one place that chooses the core.
 
     ``q [B, S, KV, G, hd]`` x ``k [B, Skv, KV, hd]``, ``v [B, Skv, KV,
-    hdv]`` -> ``out [B, S, KV, G, hdv]`` through int8 QK^T / online fp32 softmax / int8 PV with
-    in-hindsight ranges for all four tensors (q, k, v, probabilities).
-    ``sites`` is the ``{"q"/"k"/"v"/"p": {"act": leaf}}`` core-site tree
-    (see ``models.attention.init_attention_sites``); returns ``(out,
-    stats)`` with a stats tree of the same structure.
+    hdv]`` -> ``out [B, S, KV, G, hdv]``.  ``sites`` is the ``{"q"/"k"/
+    "v"/"p": {"act": leaf}}`` core-site tree (see
+    ``models.attention.init_attention_sites``); returns ``(out, stats)``
+    with a stats tree of the same structure.
 
     The block plan is resolved ONCE here (``kernels.tuning``, env
-    ``REPRO_ATTN_BLOCK``) and baked into the static schedule both
-    backends replay — tile choice changes speed, never results.  So is
-    which probability statistics the core computes: only the (min, max)
-    the range update reads, unless the policy's telemetry reads the
-    clip/SQNR counters too.
+    ``REPRO_ATTN_BLOCK``) and both cores walk it.  A policy with static
+    8-bit activation ranges (``qattention_eligible``) runs the int8 core:
+    int8 QK^T / online fp32 softmax / int8 PV with in-hindsight ranges for
+    q, k, v and the probabilities, on the backend's kernel or its replay;
+    tile choice changes speed, never results.  So is which probability
+    statistics it computes: only the (min, max) the range update reads,
+    unless the policy's telemetry reads the clip/SQNR counters too.  Any
+    other policy runs the fp core on the on-grid q/k/v and marks every
+    core site "not visited", so its state passes through the estimator
+    update unchanged.
     """
     b, s, kvh, g, hd = q.shape
     skv, hdv = k.shape[1], v.shape[-1]
+    mod = _attn_mod()
+    from repro.kernels import tuning as _tuning
+    bq, bkv = _tuning.attention_block(s, skv, hd)
+    sched = mod.make_schedule(
+        sq=s, skv=skv, hd=hd, bq=bq, bkv=bkv, groups=g, mode=mode,
+        window=int(window or 0), prefix_len=int(prefix_len or 0),
+        sm_scale=float(scale), hdv=hdv,
+        stats="full" if policy.telemetry.enabled else "range")
+
+    # Head-major flatten (exact: transposes/reshapes move values, not
+    # bits): q -> [B*KV*G, S, hd], k/v -> [B*KV, Skv, hd].  The outer AD
+    # differentiates through these, so the cores only see the flattened
+    # layout.
+    def qflat(t):
+        return jnp.transpose(t, (0, 2, 3, 1, 4)).reshape(b * kvh * g, s, hd)
+
+    def kvflat(t):
+        return jnp.transpose(t, (0, 2, 1, 3)).reshape(
+            b * kvh, skv, t.shape[-1])
+
+    def unflat(out3):
+        return jnp.transpose(out3.reshape(b, kvh, g, s, hdv),
+                             (0, 3, 1, 2, 4)).astype(q.dtype)
+
+    def kvlen():
+        if kv_len is None:
+            return jnp.full((1, 1), skv, jnp.int32)
+        return jnp.asarray(kv_len, jnp.int32).reshape(1, 1)
+
+    if not qattention_eligible(policy):
+        with jax.named_scope("attn_fp"):
+            out = unflat(mod.attention_core_fp(
+                qflat(q), kvflat(k), kvflat(v), kvlen(), sched=sched))
+        return out, jax.tree_util.tree_map(lambda _: stats_zeros(policy),
+                                           sites)
+
     cfg = policy.act_estimator
     with jax.named_scope(f"qattn_int8_{policy.backend}"):
         qh, q_st, q_qt = site_quantize(policy, q, sites["q"]["act"], step,
@@ -716,31 +762,7 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
             q_qt.zero_point, alpha_qk, scale_p, zp_p, alpha_pv,
             p_lo, p_hi, jnp.float32(0.0),
         ]).astype(jnp.float32).reshape(1, 8)
-        if kv_len is None:
-            kvl = jnp.full((1, 1), skv, jnp.int32)
-        else:
-            kvl = jnp.asarray(kv_len, jnp.int32).reshape(1, 1)
-
-        mod = _attn_mod()
-        from repro.kernels import tuning as _tuning
-        bq, bkv = _tuning.attention_block(s, skv, hd)
-        sched = mod.make_schedule(
-            sq=s, skv=skv, hd=hd, bq=bq, bkv=bkv, groups=g, mode=mode,
-            window=int(window or 0), prefix_len=int(prefix_len or 0),
-            sm_scale=float(scale), hdv=hdv,
-            stats="full" if policy.telemetry.enabled else "range")
-
-        # Head-major flatten (exact: transposes/reshapes move values, not
-        # bits): q -> [B*KV*G, S, hd], k/v -> [B*KV, Skv, hd].  The outer
-        # AD differentiates through these, so the custom_vjp only handles
-        # the flattened layout.
-        def qflat(t):
-            return jnp.transpose(t, (0, 2, 3, 1, 4)).reshape(
-                b * kvh * g, s, hd)
-
-        def kvflat(t):
-            return jnp.transpose(t, (0, 2, 1, 3)).reshape(
-                b * kvh, skv, t.shape[-1])
+        kvl = kvlen()
 
         fused = policy.backend == FUSED
         qat = _QATTN_CACHE.get_or_build(
@@ -748,8 +770,7 @@ def qattention(policy, q: jax.Array, k: jax.Array, v: jax.Array,
         out3, stats6 = qat(qflat(qh), kvflat(kh), kvflat(vh),
                            qflat(q_qt.q), kvflat(k_qt.q), kvflat(v_qt.q),
                            jax.lax.stop_gradient(regs), kvl)
-        out = jnp.transpose(out3.reshape(b, kvh, g, s, hdv),
-                            (0, 3, 1, 2, 4)).astype(q.dtype)
+        out = unflat(out3)
         p_st = _pstats_vector(policy, stats6, p_lo, p_hi)
         sg = jax.lax.stop_gradient
         stats = {"q": {"act": sg(q_st)}, "k": {"act": sg(k_st)},

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from . import backend, estimators, quant
-from .backend import QTensor  # re-exported for site callers
+from .backend import QTensor, stats_zeros  # re-exported for site callers
 from .lru import LruCache
 from .policy import QuantPolicy
 from .state import INITED, QMAX, QMIN, init_range_state
@@ -134,11 +134,6 @@ def _gathered_fwd(x, qmin, qmax, spec):
 # estimator update ONCE per optimizer step — matching the paper's
 # one-update-per-iteration semantics under grad accumulation.
 # ---------------------------------------------------------------------------
-def stats_zeros(policy: QuantPolicy) -> jax.Array:
-    """A "site not visited" stats vector of the policy's stat width."""
-    return jnp.zeros((policy.stat_width,), jnp.float32)
-
-
 def act_quant_site(
     x: jax.Array,
     leaf: jax.Array,

@@ -74,7 +74,7 @@ MASK_MODES = ("causal", "sliding", "prefix", "cross", "bidir")
 
 
 # ---------------------------------------------------------------------------
-# Schedule: the static block plan shared by kernel, reference and backward.
+# Schedule: the static block plan of kernel, reference, backward, fp core.
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class AttnSchedule:
@@ -215,7 +215,7 @@ def _block_visited(i, ki, sched: AttnSchedule, xp=jnp):
 
 
 def _element_mask(q_pos, k_pos, kvlen, sched: AttnSchedule):
-    """Boolean attend-mask, matching ``models.attention._mask_block`` plus
+    """Boolean attend-mask of every core, with the runtime kv length and
     the static skv bound (kills block-padding / OOB-read garbage)."""
     if sched.mode in ("cross", "bidir"):
         m = jnp.ones(jnp.broadcast_shapes(q_pos.shape, k_pos.shape), bool)
@@ -615,6 +615,72 @@ def attention_core_reference(q_u8, k_i8, v_i8, regs, kvlen, *,
         bh, S.nq * S.bq, 2)[:, :S.sq]
     pstats = jnp.transpose(sts, (1, 2, 0, 3)).reshape(bh, S.nq, STAT_SLOTS)
     return out, ml, pstats
+
+
+# ---------------------------------------------------------------------------
+# The fp core: policies without static 8-bit ranges (dynamic, disabled,
+# other widths) have no int8 core, and run the same block walk in fp32.
+# ---------------------------------------------------------------------------
+def attention_core_fp(q, k, v, kvlen, *, sched: AttnSchedule):
+    """fp32 online-softmax attention on the schedule's block walk.
+
+    ``q [BH, sq, hd]``, ``k [ZB, skv, hd]`` and ``v [ZB, skv, hdv]`` in any
+    float dtype, ``kvlen`` int32 [1, 1]; returns ``out [BH, sq, hdv]`` f32.
+    Each q block walks the ``sched.width`` kv blocks from
+    ``_kv_block_base`` (O(S * w) under a sliding window) and computes only
+    those ``_block_visited`` keeps.  Masked scores take NEG_INF and an
+    exact 0 probability, and the denominator is floored at 1e-30, so a row
+    with no attended key returns 0.  JAX differentiates it as written.
+    """
+    S = sched
+    bh = q.shape[0]
+    zb = bh // S.groups
+    qz = _pad_axis(q.astype(jnp.float32) * S.sm_scale, S.nq * S.bq,
+                   1).reshape(zb, S.groups, S.nq, S.bq, S.hd)
+    kz = _pad_axis(k.astype(jnp.float32), S.nkv * S.bkv, 1).reshape(
+        zb, S.nkv, S.bkv, S.hd)
+    vz = _pad_axis(v.astype(jnp.float32), S.nkv * S.bkv, 1).reshape(
+        zb, S.nkv, S.bkv, S.hdv)
+    kvl = kvlen[0, 0]
+
+    def q_body(i):
+        qb = jax.lax.dynamic_index_in_dim(qz, i, 2, keepdims=False)
+        base = _kv_block_base(i, S)
+
+        def block(carry, ki):
+            m, l, acc = carry
+            kb = jax.lax.dynamic_index_in_dim(kz, ki, 1, keepdims=False)
+            vb = jax.lax.dynamic_index_in_dim(vz, ki, 1, keepdims=False)
+            q_pos = i * S.bq + jax.lax.broadcasted_iota(
+                jnp.int32, (S.bq, S.bkv), 0)
+            k_pos = ki * S.bkv + jax.lax.broadcasted_iota(
+                jnp.int32, (S.bq, S.bkv), 1)
+            mask = _element_mask(q_pos, k_pos, kvl, S)
+            s = jnp.where(mask, jnp.einsum("zgqh,zkh->zgqk", qb, kb), NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * corr + jnp.einsum("zgqk,zkh->zgqh", p, vb)
+            return m_new, l, acc
+
+        def kv_body(carry, t):
+            ki = base + t
+            vis = _block_visited(i, ki, S)
+            if vis is None:
+                return block(carry, ki), None
+            return jax.lax.cond(vis, block, lambda c, _: c, carry, ki), None
+
+        m0 = jnp.full((zb, S.groups, S.bq, 1), NEG_INF, jnp.float32)
+        l0 = jnp.zeros((zb, S.groups, S.bq, 1), jnp.float32)
+        a0 = jnp.zeros((zb, S.groups, S.bq, S.hdv), jnp.float32)
+        (_, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0),
+                                      jnp.arange(S.width))
+        return acc / jnp.maximum(l, 1e-30)
+
+    outs = jax.lax.map(q_body, jnp.arange(S.nq))     # [nq, ZB, G, bq, hdv]
+    return jnp.transpose(outs, (1, 2, 0, 3, 4)).reshape(
+        bh, S.nq * S.bq, S.hdv)[:, :S.sq]
 
 
 # ---------------------------------------------------------------------------

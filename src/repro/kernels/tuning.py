@@ -153,10 +153,11 @@ def matmul_block(m: int, n: int, k: int, dtype="int8",
 def attention_block(sq: int, skv: int, hd: int, dtype="int8",
                     bench_thunk: Optional[Callable] = None
                     ) -> Tuple[int, int]:
-    """(bq, bkv) for the fused attention kernel.  Env ``REPRO_ATTN_BLOCK``
-    wins.  The choice is made once at dispatch and shared by BOTH backends
-    (the simulated reference replays the identical block schedule), so
-    tuning cannot break the bit-parity contract."""
+    """(bq, bkv) of the attention block plan.  Env ``REPRO_ATTN_BLOCK``
+    wins.  The choice is made once at dispatch (``backend.attention``)
+    and walked by both cores: the int8 core on BOTH backends (the
+    simulated reference replays the identical block schedule, so tuning
+    cannot break the bit-parity contract) and the fp core."""
     override = _parse_env("REPRO_ATTN_BLOCK", 2)
     if override is not None:
         return override

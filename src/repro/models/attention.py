@@ -1,31 +1,29 @@
-"""Attention: GQA/MQA with chunked (flash-style) online-softmax compute.
+"""Attention layers: GQA/MQA and multi-head latent attention.
 
 Covers every attention pattern in the assigned architecture pool:
 
   * ``causal``   — full causal self-attention (dense LMs, MoE LMs)
   * ``sliding``  — causal within a window (StarCoder2 w=4096,
-                   RecurrentGemma local attention w=2048); gets a
-                   block-local fast path (each q block attends only its own
-                   + previous kv block) so FLOPs/memory are O(S·w), which
-                   is what makes ``long_500k`` runnable for these archs
+                   RecurrentGemma local attention w=2048)
   * ``prefix``   — prefix-LM mask (PaliGemma: bidirectional over the image
                    prefix, causal after)
   * ``cross``    — encoder-decoder cross attention (SeamlessM4T)
 
 All projections (q, k, v, o) run through the paper's quantized data path
 (:func:`repro.core.qlinear.qdense`), so W8/A8/G8 in-hindsight quantization
-applies uniformly.  Softmax statistics are fp32.  The chunked core keeps
-peak memory at O(q_chunk x kv_chunk) score tiles, which is required for the
-``prefill_32k`` shapes (a naive 32k x 32k score tensor would not fit VMEM
-or HBM on the production mesh).
+applies uniformly.  Training and prefill hand the core to the one
+attention-core site, :func:`repro.core.backend.attention`: the int8 flash
+core for static 8-bit policies, the fp core otherwise, both on one block
+plan that skips fully masked blocks and walks only a window's blocks
+(``kernels/int8_attention.py``).  This module keeps the projections and
+decode: one new token against a cache (``_decode_attn``).
 
 KV caches are plain pytrees ``{"k": [B, L, KV, hd], "v": ..., "pos":
 int32[]}``; sliding-window caches are ring buffers of length ``window``
-(constant memory for ``long_500k`` decode).
+(constant memory in decode).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
@@ -74,7 +72,7 @@ def init_attention(key, d_model: int, n_heads: int, n_kv: int, head_dim: int,
 
 def init_attention_sites() -> dict:
     sites = {name: qlinear.init_site() for name in ("q", "k", "v", "o")}
-    # The attention CORE's quant sites (backend.qattention): hindsight
+    # The attention CORE's quant sites (backend.attention): hindsight
     # ranges for the rope'd q/k, v, and the softmax probabilities.  The
     # probability leaf is initialized a-priori to the softmax codomain
     # [0, 1] — its range is consumed mid-kernel, before the tensor
@@ -88,136 +86,6 @@ def init_attention_sites() -> dict:
         "p": {"act": make_range_state(0.0, 1.0)},
     }
     return sites
-
-
-# ---------------------------------------------------------------------------
-# Mask helpers (positions are absolute token indices).
-# ---------------------------------------------------------------------------
-def _mask_block(q_pos, kv_pos, mode: str, window: Optional[int],
-                prefix_len: Optional[int], kv_len: Optional[jax.Array]):
-    """Boolean [q, k] mask block: True = attend."""
-    q = q_pos[:, None]
-    k = kv_pos[None, :]
-    if mode in ("cross", "bidir"):
-        m = jnp.ones((q_pos.shape[0], kv_pos.shape[0]), bool)
-    elif mode == "prefix":
-        m = (k <= q) | (k < prefix_len)
-    elif mode == "sliding":
-        m = (k <= q) & (q - k < window)
-    else:  # causal
-        m = k <= q
-    if kv_len is not None:
-        m = m & (k < kv_len)
-    return m
-
-
-# ---------------------------------------------------------------------------
-# Chunked online-softmax attention core.
-# q: [B, Sq, KV, G, hd]   k/v: [B, Skv, KV, hd]
-# ---------------------------------------------------------------------------
-def _chunked_attn(q, k, v, *, mode: str, window, prefix_len, kv_len,
-                  q_start: int, q_chunk: int, kv_chunk: int, scale: float):
-    b, sq, nkv, g, hd = q.shape
-    skv, hdv = k.shape[1], v.shape[-1]
-    qc = min(q_chunk, sq)
-    kc = min(kv_chunk, skv)
-    # configs pick chunk sizes that divide the shape; assert to fail loudly.
-    assert sq % qc == 0 and skv % kc == 0, (sq, qc, skv, kc)
-    nq, nk = sq // qc, skv // kc
-
-    qb = q.reshape(b, nq, qc, nkv, g, hd)
-    kb = k.reshape(b, nk, kc, nkv, hd)
-    vb = v.reshape(b, nk, kc, nkv, hdv)
-
-    def q_body(qi):
-        qblk = qb[:, qi].astype(jnp.float32) * scale   # [B, qc, KV, G, hd]
-        q_pos = q_start + qi * qc + jnp.arange(qc)
-
-        def kv_body(carry, ki):
-            m, l, acc = carry
-            kblk = kb[:, ki].astype(jnp.float32)       # [B, kc, KV, hd]
-            vblk = vb[:, ki].astype(jnp.float32)
-            kv_pos = ki * kc + jnp.arange(kc)
-            s = jnp.einsum("bqngh,bknh->bngqk", qblk, kblk)   # GQA: g broadcast
-            mask = _mask_block(q_pos, kv_pos, mode, window, prefix_len, kv_len)
-            s = jnp.where(mask[None, None, None], s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            corr = jnp.exp(m - m_new)
-            l = l * corr + jnp.sum(p, axis=-1)
-            acc = acc * corr[..., None] + jnp.einsum("bngqk,bknh->bngqh",
-                                                     p, vblk)
-            return (m_new, l, acc), None
-
-        m0 = jnp.full((b, nkv, g, qc), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((b, nkv, g, qc), jnp.float32)
-        a0 = jnp.zeros((b, nkv, g, qc, hdv), jnp.float32)
-        (m, l, acc), _ = jax.lax.scan(kv_body, (m0, l0, a0), jnp.arange(nk))
-        out = acc / jnp.maximum(l, 1e-30)[..., None]          # [B, KV, G, qc, hd]
-        return jnp.transpose(out, (0, 3, 1, 2, 4))            # [B, qc, KV, G, hd]
-
-    out = jax.lax.map(q_body, jnp.arange(nq))                  # [nq, B, qc, ...]
-    out = jnp.transpose(out, (1, 0, 2, 3, 4, 5)).reshape(b, sq, nkv, g, hdv)
-    return out.astype(q.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Dense (single-tile) attention for short sequences.
-#
-# For train-time S<=dense_attn_max the full [S, Skv] score tile is cheaper
-# than the chunked scan: JAX AD of the online-softmax scan stacks per-chunk
-# residuals (measured as the dominant HBM-traffic term, EXPERIMENTS.md
-# §Perf), while the dense tile is a remat-transient the backward recomputes
-# in one fused pass.  Long prefill shapes keep the chunked path.
-# ---------------------------------------------------------------------------
-def _dense_attn(q, k, v, *, mode: str, window, prefix_len, kv_len,
-                scale: float):
-    b, sq, nkv, g, hd = q.shape
-    skv = k.shape[1]
-    s = jnp.einsum("bqngh,bknh->bngqk", q.astype(jnp.float32) * scale,
-                   k.astype(jnp.float32))
-    mask = _mask_block(jnp.arange(sq), jnp.arange(skv), mode, window,
-                       prefix_len, kv_len)
-    s = jnp.where(mask[None, None, None], s, NEG_INF)
-    m = jnp.max(s, axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    out = jnp.einsum("bngqk,bknh->bngqh", p, v.astype(jnp.float32))
-    out = out / jnp.maximum(jnp.sum(p, axis=-1), 1e-30)[..., None]
-    return jnp.transpose(out, (0, 3, 1, 2, 4)).astype(q.dtype)
-
-
-# ---------------------------------------------------------------------------
-# Block-local fast path for sliding windows (training / prefill).
-# Each q block of size w attends its own + the previous kv block only:
-# O(S * 2w) compute instead of O(S^2) — the sub-quadratic property that
-# makes sliding-window archs eligible for long contexts.
-# ---------------------------------------------------------------------------
-def _local_attn(q, k, v, *, window: int, scale: float):
-    b, s, nkv, g, hd = q.shape
-    assert s % window == 0, (s, window)
-    nblk = s // window
-    w = window
-    qb = q.reshape(b, nblk, w, nkv, g, hd).astype(jnp.float32) * scale
-    kb = k.reshape(b, nblk, w, nkv, hd).astype(jnp.float32)
-    vb = v.reshape(b, nblk, w, nkv, hd).astype(jnp.float32)
-    kprev = jnp.concatenate([jnp.zeros_like(kb[:, :1]), kb[:, :-1]], axis=1)
-    vprev = jnp.concatenate([jnp.zeros_like(vb[:, :1]), vb[:, :-1]], axis=1)
-    k2 = jnp.concatenate([kprev, kb], axis=2)                  # [B, nblk, 2w, KV, hd]
-    v2 = jnp.concatenate([vprev, vb], axis=2)
-
-    s_ = jnp.einsum("bnqkgh,bnmkh->bnkgqm", qb, k2)            # [B,nblk,KV,G,w,2w]
-    qpos = jnp.arange(w)[:, None]
-    kpos = jnp.arange(2 * w)[None, :] - w
-    valid = (kpos <= qpos) & (qpos - kpos < w)
-    blk = jnp.arange(nblk)[:, None, None]
-    # block 0 has no previous block: mask its first-half columns.
-    valid = valid[None] & ((blk > 0) | (kpos >= 0))     # [nblk, w, 2w]
-    s_ = jnp.where(valid[None, :, None, None], s_, NEG_INF)
-    m = jnp.max(s_, axis=-1, keepdims=True)
-    p = jnp.exp(s_ - m)
-    out = jnp.einsum("bnkgqm,bnmkh->bnqkgh", p, v2) / jnp.maximum(
-        jnp.sum(p, axis=-1), 1e-30)[..., None].transpose(0, 1, 4, 2, 3, 5)
-    return out.reshape(b, s, nkv, g, hd).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -356,13 +224,9 @@ def attention_layer(
     policy: QuantPolicy,
     seed: jax.Array,
     step: jax.Array,
-    q_chunk: int = 2048,
-    kv_chunk: int = 1024,
-    dense_attn_max: int = 4096,
 ) -> tuple[jax.Array, dict, Optional[dict]]:
     """Returns (y, new_sites, new_cache)."""
     b, s, _ = x.shape
-    g = n_heads // n_kv
     scale = head_dim ** -0.5
     src = x if kv_x is None else kv_x
 
@@ -370,7 +234,6 @@ def attention_layer(
     # time (signalled by kv_x=None) — no k/v projection runs here.
     cross_decode = cache is not None and mode == "cross" and kv_x is None
     new_sites = {}
-    core_stats = None  # set when the quantized attention core runs
     # ONE shared activation quantization for q/k/v (paper: Q_Y quantizes
     # each tensor once; per-consumer re-quantization would triple the
     # fake-quant traffic).  Its range state lives on the "q" site.
@@ -412,15 +275,7 @@ def attention_layer(
         q = apply_rope(q, positions, rope_theta)
         k = apply_rope(k, positions, rope_theta)
 
-    # Head- or sequence-parallel attention core (see sharding.attn_hints):
-    # sequence sharding is only legal on the dense path (the chunked path
-    # scans over the sequence, and decode has S=1).
-    will_use_dense = (cache is None and not
-                      (mode == "sliding" and window is not None
-                       and s > window and s % window == 0)
-                      and k is not None
-                      and max(s, k.shape[1]) <= dense_attn_max and s > 1)
-    q, k, v = attn_hints(q, k, v, allow_seq=will_use_dense)
+    q, k, v = attn_hints(q, k, v)
 
     new_cache = None
     if cross_decode:
@@ -439,48 +294,19 @@ def attention_layer(
                            prefix_len=prefix_len, scale=scale,
                            kv_scale=new_cache.get("scale"))
     else:
-        # training / prefill compute; optionally fill the cache.
-        # Static-range policies route the core through the
-        # backend-dispatched int8 flash kernel (backend.qattention): QK^T
-        # and PV run as int8 contractions with in-hindsight ranges for
-        # q/k/v and the softmax probabilities, and the probability-site
-        # statistics come back from the kernel's resident tiles.  The
-        # schedule needs static mask geometry, so traced window/prefix
-        # bounds keep the fp einsum path (kv_len stays a runtime operand).
-        use_core = (
-            "core" in sites and s > 1
-            and backend.qattention_eligible(policy)
-            and (mode != "sliding" or isinstance(window, int))
-            and (mode != "prefix" or isinstance(prefix_len, int))
-        )
-        if use_core:
-            out, core_stats = backend.qattention(
-                policy, q, k, v, sites["core"], mode=mode, window=window,
-                prefix_len=prefix_len, kv_len=kv_len, scale=scale,
-                step=step)
-        elif mode == "sliding" and window is not None and s > window \
-                and s % window == 0:
-            out = _local_attn(q, k, v, window=window, scale=scale)
-        elif max(s, k.shape[1]) <= dense_attn_max:
-            out = _dense_attn(q, k, v, mode=mode, window=window,
-                              prefix_len=prefix_len, kv_len=kv_len,
-                              scale=scale)
-        else:
-            out = _chunked_attn(q, k, v, mode=mode, window=window,
-                                prefix_len=prefix_len, kv_len=kv_len,
-                                q_start=0, q_chunk=q_chunk,
-                                kv_chunk=kv_chunk, scale=scale)
+        # training / prefill: the attention-core site chooses the core;
+        # optionally fill the cache.
+        out, new_sites["core"] = backend.attention(
+            policy, q, k, v, sites["core"], mode=mode, window=window,
+            prefix_len=prefix_len, kv_len=kv_len, scale=scale, step=step)
         if cache is not None:
             new_cache = cache_fill(cache, k, v)
-
-    if "core" in sites:
-        if core_stats is None:
-            # core didn't run this call (decode / fp path): mark every
-            # core site "not visited" so its state passes through the
-            # estimator update unchanged.
-            core_stats = jax.tree_util.tree_map(
-                lambda _: qlinear.stats_zeros(policy), sites["core"])
-        new_sites["core"] = core_stats
+    if "core" not in new_sites:
+        # decode: the core did not run; mark every core site "not
+        # visited" so its state passes through the estimator update
+        # unchanged.
+        new_sites["core"] = jax.tree_util.tree_map(
+            lambda _: qlinear.stats_zeros(policy), sites["core"])
 
     y, new_sites["o"] = qlinear.qeinsum("bskgh,kghd->bsd", out, params["wo"],
                                         sites["o"], policy, seed=seed + 3,
@@ -495,7 +321,7 @@ def attention_layer(
 # prefill.  k and v come up from a ``kv_lora_rank``-wide latent (RMSNorm'd
 # in fp) through one projection; a ``rope``-wide RoPE key shared by every
 # head is concatenated after each head's ``k_nope``.  Every projection is a
-# quantized site; the core is the same ``backend.qattention`` site, with a
+# quantized site; the core is the same ``backend.attention`` site, with a
 # query-key head dim (nope + rope) above the value head dim.
 # ---------------------------------------------------------------------------
 def init_mla(key, d_model: int, n_heads: int, nope: int, rope: int,
@@ -533,9 +359,8 @@ def rope_halves(x: jax.Array) -> jax.Array:
 def mla_layer(params: dict, sites: dict, x: jax.Array, *, n_heads: int,
               nope: int, rope: int, v_dim: int, rank: int, rope_theta: float,
               norm_eps: Optional[float], positions: Optional[jax.Array] = None,
-              policy: QuantPolicy, seed: jax.Array, step: jax.Array,
-              q_chunk: int = 2048, kv_chunk: int = 1024,
-              dense_attn_max: int = 4096) -> tuple[jax.Array, dict]:
+              policy: QuantPolicy, seed: jax.Array,
+              step: jax.Array) -> tuple[jax.Array, dict]:
     """Causal latent attention over ``x [B, S, D]``; returns ``(y,
     new_sites)``."""
     b, s, _ = x.shape
@@ -569,22 +394,9 @@ def mla_layer(params: dict, sites: dict, x: jax.Array, *, n_heads: int,
     v = kv[..., nope:]
     scale = (nope + rope) ** -0.5
 
-    core_stats = None
-    if backend.qattention_eligible(policy):
-        out, core_stats = backend.qattention(
-            policy, qh, kh, v, sites["core"], mode="causal", scale=scale,
-            step=step)
-    elif s <= dense_attn_max:
-        out = _dense_attn(qh, kh, v, mode="causal", window=None,
-                          prefix_len=None, kv_len=None, scale=scale)
-    else:
-        out = _chunked_attn(qh, kh, v, mode="causal", window=None,
-                            prefix_len=None, kv_len=None, q_start=0,
-                            q_chunk=q_chunk, kv_chunk=kv_chunk, scale=scale)
-    if core_stats is None:
-        core_stats = jax.tree_util.tree_map(
-            lambda _: qlinear.stats_zeros(policy), sites["core"])
-    new_sites["core"] = core_stats
+    out, new_sites["core"] = backend.attention(
+        policy, qh, kh, v, sites["core"], mode="causal", scale=scale,
+        step=step)
     y, new_sites["o"] = qlinear.qeinsum("bshe,hed->bsd", out[:, :, :, 0],
                                         params["o_proj"], sites["o"], policy,
                                         seed=seed + 3, step=step)

@@ -204,9 +204,7 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
                 nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
                 v_dim=cfg.v_head_dim, rank=cfg.kv_lora_rank,
                 rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
-                positions=positions, policy=policy, seed=seed, step=step,
-                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                dense_attn_max=cfg.dense_attn_max)
+                positions=positions, policy=policy, seed=seed, step=step)
             kv = None
         else:
             a, new_sites["attn"], kv = attn.attention_layer(
@@ -215,9 +213,7 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
                 mode=mode, window=window, prefix_len=prefix_len,
                 rope_theta=cfg.rope_theta, positions=positions,
                 cache=None if cache is None else cache["kv"],
-                policy=policy, seed=seed, step=step,
-                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-                dense_attn_max=cfg.dense_attn_max)
+                policy=policy, seed=seed, step=step)
         x = x + a
         if cache is not None:
             new_cache["kv"] = kv
@@ -230,8 +226,7 @@ def _apply_block(kind: str, params, sites, x, *, cfg, policy, seed, step,
                 mode="cross", rope_theta=None, positions=positions,
                 kv_x=enc_out, kv_len=enc_len,
                 cache=None if cache is None else cache["xkv"],
-                policy=policy, seed=seed + 8, step=step,
-                q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
+                policy=policy, seed=seed + 8, step=step)
             x = x + a
             if cache is not None:
                 new_cache["xkv"] = xkv

@@ -81,44 +81,14 @@ def replicate_hint(x):
     return jax.lax.with_sharding_constraint(x, P())
 
 
-def attn_hints(q, k, v, *, allow_seq: bool):
-    """Sharding for the attention core [B, S, KV, G, hd] / [B, S, KV, hd].
-
-    Preference order:
-      1. exact head sharding (KV or G divides the model axis),
-      2. SEQUENCE sharding of the core (context parallelism) when the
-         dense-attention path allows it — for archs whose head counts do
-         not divide (starcoder2: G=9/12, nemotron: KV=8, G=12,
-         command-r: 8/8) this is the only layout where BOTH the attention
-         compute AND the token-contracted weight gradients dW = x^T g
-         shard exactly; padded head sharding leaves dW model-REPLICATED
-         (measured: 33% of total step FLOPs — EXPERIMENTS.md §Perf),
-      3. padded head sharding (decode / chunked paths where the scan dim
-         cannot be sharded).
-    """
-    if _HINTS is None:
-        return q, k, v
-    maxis = _HINTS.get("model")
-    msize = _HINTS.get("model_size")
-    bspec = _HINTS.get("batch")
-    if maxis is None or not msize:
-        return q, k, v
-    kv, g, s = q.shape[2], q.shape[3], q.shape[1]
-    if kv % msize == 0 or g % msize == 0:
-        q = hint_heads(q, kv_axis=2, g_axis=3)
-        if k is not None:
-            k = hint_heads(k, kv_axis=2, g_axis=2)
-            v = hint_heads(v, kv_axis=2, g_axis=2)
-        return q, k, v
-    if allow_seq and s % msize == 0:
-        spec_q = P(bspec, maxis, None, None, None)
-        spec_kv = P(bspec, maxis, None, None)
-        q = jax.lax.with_sharding_constraint(q, spec_q)
-        if k is not None:
-            k = jax.lax.with_sharding_constraint(k, spec_kv)
-            v = jax.lax.with_sharding_constraint(v, spec_kv)
-        return q, k, v
+def attn_hints(q, k, v):
+    """Head sharding for the attention core [B, S, KV, G, hd] / [B, S, KV,
+    hd] (``hint_heads``): q's heads exactly where KV or G divides the
+    model axis and padded otherwise; k/v's only where KV divides it."""
     q = hint_heads(q, kv_axis=2, g_axis=3)
+    if k is not None:
+        k = hint_heads(k, kv_axis=2, g_axis=2)
+        v = hint_heads(v, kv_axis=2, g_axis=2)
     return q, k, v
 
 

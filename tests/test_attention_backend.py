@@ -188,7 +188,7 @@ def _trace_qattention(policy):
         sites = tmetrics.widen_state(sites, policy.stat_width)
 
     def f(q, k, v):
-        out, stats = backend.qattention(policy, q, k, v, sites,
+        out, stats = backend.attention(policy, q, k, v, sites,
                                         mode="causal", scale=HD ** -0.5,
                                         step=jnp.int32(3))
         return out, stats
@@ -316,6 +316,88 @@ def test_sliding_skip_outside_window_is_exact(monkeypatch):
         for name, x, y in zip(("out", "ml"), skip[0], every):
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y),
                                           err_msg=f"every tile: {name}")
+
+
+# ---------------------------------------------------------------------------
+# The fp core: the same block walk in fp32, against a plain dense softmax.
+# ---------------------------------------------------------------------------
+def _dense_masked_softmax(q, k, v, *, mode, scale, window=0, prefix_len=0,
+                          kv_len=None):
+    """Plain attention on the cores' flattened layout: q [ZB, G, sq, hd],
+    k [ZB, skv, hd], v [ZB, skv, hdv] -> [ZB, G, sq, hdv]; the whole
+    [sq, skv] score tile at once."""
+    sq, skv = q.shape[2], k.shape[1]
+    qp = jnp.arange(sq)[:, None]
+    kp = jnp.arange(skv)[None, :]
+    if mode in ("cross", "bidir"):
+        mask = jnp.ones((sq, skv), bool)
+    elif mode == "prefix":
+        mask = (kp <= qp) | (kp < prefix_len)
+    elif mode == "sliding":
+        mask = (kp <= qp) & (qp - kp < window)
+    else:
+        mask = kp <= qp
+    if kv_len is not None:
+        mask = mask & (kp < kv_len)
+    s = jnp.einsum("zgqh,zkh->zgqk", q, k) * scale
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum("zgqk,zkh->zgqh", p, v)
+
+
+FP_CORE_CASES = [
+    # (mode, sq, skv, groups, hd, hdv, mode kwargs, runtime kv_len)
+    ("causal", 40, 40, 1, 16, 16, {}, None),
+    ("sliding", 48, 48, 1, 16, 16, {"window": 12}, None),
+    ("prefix", 40, 40, 1, 16, 16, {"prefix_len": 13}, None),
+    ("cross", 24, 40, 1, 16, 16, {}, 29),
+    ("bidir", 32, 32, 1, 16, 16, {}, None),
+    ("causal", 32, 32, 3, 16, 16, {}, None),
+    ("causal", 32, 32, 2, 24, 16, {}, None),
+]
+
+
+@pytest.mark.parametrize(
+    "mode,sq,skv,g,hd,hdv,kw,kv_len", FP_CORE_CASES,
+    ids=["causal", "sliding", "prefix", "cross-kvlen", "bidir", "gqa",
+         "hdv-below-hd"])
+def test_fp_core_matches_dense_softmax(mode, sq, skv, g, hd, hdv, kw,
+                                       kv_len):
+    """``attention_core_fp`` on blocks of 8 (several q and kv blocks, the
+    narrowed width under a sliding window, skipped blocks under causal and
+    prefix masks) equals a plain dense masked softmax, and so do the
+    gradients JAX takes through its walk."""
+    zb, scale = 2, hd ** -0.5
+    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(0), 4)
+    q = jax.random.normal(kq, (zb * g, sq, hd))
+    k = jax.random.normal(kk, (zb, skv, hd))
+    v = jax.random.normal(kv, (zb, skv, hdv))
+    w = jax.random.normal(kg, (zb * g, sq, hdv))
+    sched = make_schedule(sq=sq, skv=skv, hd=hd, hdv=hdv, bq=8, bkv=8,
+                          groups=g, mode=mode, sm_scale=scale, **kw)
+    assert sched.nq > 1 and sched.nkv > 1
+    if mode == "sliding":
+        assert sched.width < sched.nkv
+    kvlen = jnp.full((1, 1), skv if kv_len is None else kv_len, jnp.int32)
+
+    def core(q, k, v):
+        return ia.attention_core_fp(q, k, v, kvlen, sched=sched)
+
+    def plain(q, k, v):
+        out = _dense_masked_softmax(q.reshape(zb, g, sq, hd), k, v,
+                                    mode=mode, scale=scale, kv_len=kv_len,
+                                    **kw)
+        return out.reshape(zb * g, sq, hdv)
+
+    got, want = jax.jit(core)(q, k, v), jax.jit(plain)(q, k, v)
+    assert got.shape == (zb * g, sq, hdv) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * w),
+                              argnums=(0, 1, 2)))(q, k, v)
+             for f in (core, plain)]
+    for name, a, b in zip("qkv", *grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5, err_msg=f"d{name}")
 
 
 # ---------------------------------------------------------------------------
@@ -455,7 +537,7 @@ def test_qattention_scopes_in_hlo(bk):
     sites = attn.init_attention_sites()["core"]
 
     def f(q, k, v):
-        out, _ = backend.qattention(policy, q, k, v, sites, mode="causal",
+        out, _ = backend.attention(policy, q, k, v, sites, mode="causal",
                                     scale=HD ** -0.5, step=jnp.int32(0))
         return out.sum()
 
@@ -504,10 +586,13 @@ def test_fused_step_does_not_materialize_score_tile(monkeypatch):
     seq = 64
     monkeypatch.setenv("REPRO_ATTN_BLOCK", "16,16")
     tuning.clear_cache()
-    # Sanity: the detector sees the [S, S] tile on the fp einsum path
-    # (a dynamic-range policy keeps the dense attention einsums).
-    fp_txt = _train_step_hlo(QuantPolicy.w8a8g8(act_kind="current"), seq)
-    assert _score_tile_ops(fp_txt, seq), "detector lost the fp score tile"
+    # Sanity: the detector sees the [S, S] tile of a plain dense softmax.
+    q, k, v = (jnp.ones((2, NH // NKV, seq, HD)), jnp.ones((2, seq, HD)),
+               jnp.ones((2, seq, HD)))
+    dense_txt = jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+        _dense_masked_softmax(q, k, v, mode="causal", scale=HD ** -0.5)))
+        ).lower(q, k, v).compile().as_text()
+    assert _score_tile_ops(dense_txt, seq), "detector lost the score tile"
     # The fused flash path streams kv blocks: nothing in the whole jitted
     # train step (fwd + recompute bwd) may hold a full [S, S] fp tile.
     fused_txt = _train_step_hlo(QuantPolicy.w8a8g8(backend="fused"), seq)
@@ -518,6 +603,53 @@ def test_fused_step_does_not_materialize_score_tile(monkeypatch):
 # ---------------------------------------------------------------------------
 # Dispatch guards.
 # ---------------------------------------------------------------------------
+def _mla_run(policy):
+    nope, rope, v_dim, rank = 16, 8, 8, 16
+    params = attn.init_mla(jax.random.PRNGKey(0), D, NH, nope, rope, v_dim,
+                           rank)
+    sites = attn.init_mla_sites()
+    x = jax.random.normal(jax.random.PRNGKey(1), (B, 24, D))
+    _, ns = attn.mla_layer(params, sites, x, n_heads=NH, nope=nope,
+                           rope=rope, v_dim=v_dim, rank=rank,
+                           rope_theta=10000.0, norm_eps=1e-6, policy=policy,
+                           seed=jnp.int32(0), step=jnp.int32(0))
+    return sites, ns
+
+
+def _gqa_run(policy):
+    params, sites, x = _setup(24, policy=policy)
+    _, ns, _ = attn.attention_layer(
+        params, sites, x, n_heads=NH, n_kv=NKV, head_dim=HD, mode="causal",
+        policy=policy, seed=jnp.int32(0), step=jnp.int32(0))
+    return sites, ns
+
+
+@pytest.mark.parametrize("layer", ["attention_layer", "mla_layer"])
+@pytest.mark.parametrize("static", [True, False], ids=["static", "dynamic"])
+def test_layers_dispatch_on_the_policy(layer, static, monkeypatch):
+    """Both layers hand the core to ``backend.attention``: a static policy
+    runs the int8 core (its replay on the simulated backend), a dynamic
+    one the fp core, whose stats tree marks every core site unvisited."""
+    ran = []
+    for name in ("attention_core_reference", "attention_core_fp"):
+        orig = getattr(ia, name)
+        monkeypatch.setattr(ia, name, lambda *a, _n=name, _f=orig, **kw:
+                            ran.append(_n) or _f(*a, **kw))
+    policy = QuantPolicy.w8a8g8(
+        backend="simulated", act_kind="hindsight" if static else "current")
+    assert backend.qattention_eligible(policy) == static
+    sites, ns = (_gqa_run if layer == "attention_layer" else _mla_run)(
+        policy)
+    assert ran == (["attention_core_reference"] if static
+                   else ["attention_core_fp"])
+    core = ns["core"]
+    assert (jax.tree_util.tree_structure(core)
+            == jax.tree_util.tree_structure(sites["core"]))
+    visited = [bool(np.any(np.asarray(leaf) != 0))
+               for leaf in jax.tree_util.tree_leaves(core)]
+    assert all(visited) if static else not any(visited)
+
+
 def test_dynamic_policy_keeps_fp_path():
     policy = QuantPolicy.w8a8g8(act_kind="current")
     assert not backend.qattention_eligible(policy)

@@ -173,33 +173,3 @@ def test_rglru_scan_matches_loop():
         np.testing.assert_allclose(np.asarray(hs[:, i]), np.asarray(h),
                                    rtol=1e-5, atol=1e-6)
 
-
-def test_local_attention_matches_chunked_sliding():
-    from repro.models import attention as A
-    b, s, kv, g, hd, w = 1, 64, 2, 2, 8, 16
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (b, s, kv, g, hd))
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, kv, hd))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, kv, hd))
-    o1 = A._local_attn(q, k, v, window=w, scale=0.35)
-    o2 = A._chunked_attn(q, k, v, mode="sliding", window=w, prefix_len=None,
-                         kv_len=None, q_start=0, q_chunk=16, kv_chunk=16,
-                         scale=0.35)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=2e-3,
-                               atol=2e-4)
-
-
-def test_dense_attention_matches_chunked():
-    from repro.models import attention as A
-    b, s, kv, g, hd = 1, 32, 2, 2, 8
-    key = jax.random.PRNGKey(3)
-    q = jax.random.normal(key, (b, s, kv, g, hd))
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, s, kv, hd))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, s, kv, hd))
-    o1 = A._dense_attn(q, k, v, mode="causal", window=None, prefix_len=None,
-                       kv_len=None, scale=0.35)
-    o2 = A._chunked_attn(q, k, v, mode="causal", window=None,
-                         prefix_len=None, kv_len=None, q_start=0,
-                         q_chunk=8, kv_chunk=8, scale=0.35)
-    np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=2e-3,
-                               atol=2e-4)
